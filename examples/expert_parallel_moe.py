@@ -29,6 +29,7 @@ from repro.configs import get_reduced                    # noqa: E402
 from repro.configs.base import TrainConfig               # noqa: E402
 from repro.core import split as SP                       # noqa: E402
 from repro.data import tokens                            # noqa: E402
+from repro.launch.mesh import make_mesh                  # noqa: E402
 from repro.training import loop as L                     # noqa: E402
 from repro.training import optimizer as opt              # noqa: E402
 
@@ -61,7 +62,7 @@ def main():
     ap.add_argument("--seq", type=int, default=64)
     args = ap.parse_args()
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = get_reduced("phi3.5-moe-42b-a6.6b")
     print(f"== reduced phi3.5-moe ({cfg.n_experts} experts, top-"
           f"{cfg.experts_per_tok}) on mesh {dict(mesh.shape)} ==")

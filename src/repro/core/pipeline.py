@@ -114,10 +114,9 @@ def pipeline_apply(stage_layers, bneck_head, x, positions,
             return _inner_body(stage_ids, stage_layers, head_f32, x_f32, pos)
 
     def _inner_body(stage_ids, stage_f32, head_f32, x_f32, pos):
-        # the stage id rides in as a P('pod')-sharded iota instead of
-        # jax.lax.axis_index: under partially-auto shard_map older XLA
-        # lowers axis_index on a manual axis to a PartitionId instruction
-        # the SPMD partitioner rejects
+        # the stage id rides in as a P('pod')-sharded iota, so the stage
+        # body needs no jax.lax.axis_index on the manual axis inside this
+        # partially-auto shard_map region
         stage = stage_ids[0]
         # inputs (incl. the pod-replicated stage weights) enter in fp32 —
         # XLA CPU aborts on the bf16 psum their cotangents need; compute

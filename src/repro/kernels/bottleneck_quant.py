@@ -78,4 +78,5 @@ def bottleneck_quant(x, w, *, bits: int = 8, block_m: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((block_m, N), jnp.float32)],
         interpret=interpret,
+        name="bottleneck_quant",
     )(x, w)

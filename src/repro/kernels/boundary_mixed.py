@@ -34,6 +34,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def mxu_precision(dtype):
+    """Contraction precision of an in-kernel dot on ``dtype`` operands.
+    bf16 products are exact in one MXU pass, and Mosaic refuses a
+    multi-pass (fp32) contraction of bf16 operands — which a process-wide
+    ``jax_default_matmul_precision="highest"`` would otherwise request.
+    f32 operands keep the full-precision passes."""
+    return (jax.lax.Precision.HIGHEST if jnp.dtype(dtype).itemsize >= 4
+            else jax.lax.Precision.DEFAULT)
+
+
 def _kernel(hid_ref, nch_ref, wid_ref, bit_ref, x_ref, down_ref, up_ref,
             norm_ref, out_ref, h_scr, z_scr, *, n_w: int, block_w: int,
             dtype):
@@ -62,6 +72,7 @@ def _kernel(hid_ref, nch_ref, wid_ref, bit_ref, x_ref, down_ref, up_ref,
         # round to the model dtype == XLA's own bf16-GEMM semantics, and is
         # reproducible between compiled, interpret, and oracle paths.
         z = jnp.dot(h_scr[...], down_ref[0],
+                    precision=mxu_precision(h_scr.dtype),
                     preferred_element_type=jnp.float32
                     ).astype(h_scr.dtype).astype(jnp.float32)
         lane = w * block_w + jax.lax.broadcasted_iota(
@@ -89,6 +100,7 @@ def _kernel(hid_ref, nch_ref, wid_ref, bit_ref, x_ref, down_ref, up_ref,
             codes = jnp.clip(jnp.round(z / scale), -qm, qm)
             wired = jnp.where(bits == 0, z, codes * scale)
             y = jnp.dot(wired.astype(dtype), up_ref[0],
+                        precision=mxu_precision(dtype),
                         preferred_element_type=jnp.float32)
             out_ref[...] = y.astype(out_ref.dtype)
 
@@ -190,6 +202,7 @@ def decode_tail_grouped(xp, heads, norm_scale, norm_bias, hid_g, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((P, 128), jnp.int32),
         interpret=interpret,
+        name="decode_tail",
     )(hid_g, xp, heads, norm_scale.reshape(1, d), norm_bias.reshape(1, d))
 
 
@@ -239,4 +252,5 @@ def boundary_mixed_grouped(xp, down_w, up_w, norm_scale, hid_g, nchunk_g,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((P, d), xp.dtype),
         interpret=interpret,
+        name="boundary_mixed",
     )(hid_g, nchunk_g, width_g, bits_g, xp, down_w, up_w, norm_scale)

@@ -43,4 +43,5 @@ def dequant_matmul(codes, scales, w, *, out_dtype=jnp.bfloat16,
         out_specs=pl.BlockSpec((block_m, block_d), lambda m, d: (m, d)),
         out_shape=jax.ShapeDtypeStruct((M, D), out_dtype),
         interpret=interpret,
+        name="dequant_matmul",
     )(codes, scales, w)

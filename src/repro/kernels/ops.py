@@ -1,10 +1,18 @@
 """Jit'd public wrappers for the Pallas kernels: shape-padding, block-size
 selection, and CPU (interpret-mode) dispatch so the same call sites work in
 tests and on real TPUs.
+
+Every dispatcher decides its path when the call is traced (never at
+import): Pallas on a TPU, the jnp reference on CPU, or a path forced by
+``interpret``. On a TPU a shape the kernel cannot tile still takes the jnp
+reference, but never silently: the miss is counted in :data:`FALLBACKS`
+and warned about, and ``chip_smoke.py`` fails on any entry.
 """
 from __future__ import annotations
 
+import collections
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +24,39 @@ from repro.kernels import paged_attention as _pa
 from repro.kernels import rglru_scan as _rs
 from repro.kernels import ref
 
-_ON_TPU = jax.default_backend() == "tpu"
+#: ``(kernel, reason) -> count`` of traced calls that took the jnp reference
+#: on a TPU because the kernel could not tile the shape.
+FALLBACKS: collections.Counter = collections.Counter()
+
+
+def on_tpu() -> bool:
+    """Whether the default backend is a TPU (asked at trace time)."""
+    return jax.default_backend() == "tpu"
+
+
+def _route(kernel: str, interpret, misaligned: str = "") -> tuple:
+    """(use_pallas, interpret_mode) for one traced dispatcher call.
+
+    ``interpret=None``: Pallas compiled on a TPU, the jnp reference
+    elsewhere. ``True``/``False`` force the interpreted kernel / the
+    reference (tests). ``misaligned`` names why the kernel cannot take this
+    shape; the call then takes the reference, and on a TPU that is recorded
+    in :data:`FALLBACKS`."""
+    tpu = on_tpu()
+    use = tpu if interpret is None else bool(interpret)
+    if use and misaligned:
+        if interpret is None:
+            _note_fallback(kernel, misaligned)
+        return False, False
+    return use, (not tpu if interpret is None else bool(interpret))
+
+
+def _note_fallback(kernel: str, reason: str):
+    """Record (on a TPU) that ``kernel`` took its jnp reference."""
+    if on_tpu():
+        FALLBACKS[(kernel, reason)] += 1
+        warnings.warn(f"{kernel}: jnp reference on TPU ({reason})",
+                      stacklevel=3)
 
 
 def _pick_block(dim: int, preferred: int, align: int = 128) -> int:
@@ -33,7 +73,6 @@ def _pick_block(dim: int, preferred: int, align: int = 128) -> int:
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
 def bottleneck_quant_op(x, w, *, bits: int = 8, interpret: bool | None = None):
     """Fused down-proj + int8 quantize. x: [..., K], w: [K, N]."""
-    interp = (not _ON_TPU) if interpret is None else interpret
     lead = x.shape[:-1]
     M = 1
     for s in lead:
@@ -42,7 +81,10 @@ def bottleneck_quant_op(x, w, *, bits: int = 8, interpret: bool | None = None):
     x2 = x.reshape(M, K)
     bm = _pick_block(M, 128)
     bk = _pick_block(K, 512)
+    interp = not on_tpu() if interpret is None else interpret
     if M % bm or K % bk or N % 128:
+        if interpret is None:
+            _note_fallback("bottleneck_quant", f"M={M} K={K} N={N}")
         codes, scales = ref.bottleneck_quant_ref(x2, w, bits)
     else:
         codes, scales = _bq.bottleneck_quant(x2, w, bits=bits, block_m=bm,
@@ -92,11 +134,13 @@ def boundary_mixed_op(stacked, x, mode_idx, *, dtype=jnp.bfloat16,
     also the fast CPU serving path (interpret mode is a correctness tool,
     not a speed tool).
     """
-    use_pallas = _ON_TPU if interpret is None else bool(interpret)
-    interp = (not _ON_TPU) if interpret is None else bool(interpret)
     d = x.shape[-1]
     M, _, wmax = stacked["down_w"].shape
-    if not use_pallas or d % 128 or wmax % 128:
+    use_pallas, interp = _route(
+        "boundary_mixed", interpret,
+        f"d={d} wmax={wmax} not multiples of 128" if d % 128 or wmax % 128
+        else "")
+    if not use_pallas:
         return ref.boundary_mixed_ref(stacked, x, mode_idx, dtype=dtype)
 
     B, S = x.shape[0], x.shape[1]
@@ -208,11 +252,12 @@ def decode_tail_op(x, norm_scale, norm_bias, heads, head_idx=None, *,
     CPU and non-128-aligned d/V take :func:`ref.decode_tail_ref`, which is
     expression-identical to the legacy chain. Returns int32 tokens [B, S].
     """
-    use_pallas = _ON_TPU if interpret is None else bool(interpret)
-    interp = (not _ON_TPU) if interpret is None else bool(interpret)
     B, S, d = x.shape
     V = heads.shape[1] if tied else heads.shape[2]
-    if not use_pallas or d % 128 or V % 128:
+    use_pallas, interp = _route(
+        "decode_tail", interpret,
+        f"d={d} V={V} not multiples of 128" if d % 128 or V % 128 else "")
+    if not use_pallas:
         return ref.decode_tail_ref(x, norm_scale, norm_bias, heads, head_idx,
                                    norm_kind=norm_kind, tied=tied)
     hv = jnp.swapaxes(heads, 1, 2) if tied else heads
@@ -231,35 +276,41 @@ def decode_tail_op(x, norm_scale, norm_bias, heads, head_idx=None, *,
     return tokp[dest, 0].reshape(B, S)
 
 
-def paged_kernel_eligible(*, n_q: int, n_kv: int, hd: int,
-                          page_len: int) -> bool:
-    """Whether the serving decode path should route paged attention through
-    the Pallas kernel. Only on a real TPU with MXU-aligned head and page
-    shapes — on CPU the model layer's logical-gather jnp path is both the
-    fast path and the one pinned bit-identical to dense decode (interpret
-    mode is a correctness tool, not a speed tool)."""
-    return _ON_TPU and hd % 128 == 0 and page_len % 8 == 0 \
-        and n_q % n_kv == 0
+def sublane_tile(dtype) -> int:
+    """Rows of one TPU vreg tile: 8 for 32-bit dtypes, 16 for bf16."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def paged_kernel_eligible(*, n_q: int, n_kv: int, hd: int, page_len: int,
+                          dtype=jnp.bfloat16) -> bool:
+    """Whether the serving decode path routes paged attention through the
+    Pallas kernel: on a TPU, when one page of one kv head (``[page_len,
+    hd]``) is whole tiles of ``dtype``. Elsewhere the model layer's
+    logical-gather jnp path is both the fast path and the one pinned
+    bit-identical to dense decode (interpret mode is a correctness tool,
+    not a speed tool); on a TPU a shape that misses is a recorded
+    fallback."""
+    if hd % 128 or page_len % sublane_tile(dtype):
+        why = f"page [{page_len}, {hd}] is not whole {jnp.dtype(dtype)} tiles"
+    elif n_q % n_kv:
+        why = f"n_q={n_q} is not a multiple of n_kv={n_kv}"
+    else:
+        why = ""
+    return _route("paged_attention", None, why)[0]
 
 
 def paged_attention_op(q, k_pages, v_pages, block_table, positions, *,
                        interpret: bool | None = None):
-    """Paged decode attention (dispatcher). Deliberately NOT jitted itself —
-    serving callers invoke it inside a jitted step, like the boundary op.
+    """Paged decode attention (dispatcher) — the Pallas kernel, compiled on
+    a TPU or interpreted elsewhere. Deliberately NOT jitted itself —
+    serving callers invoke it inside a jitted step, like the boundary op,
+    once :func:`paged_kernel_eligible` has accepted the shapes.
 
     q: [B, nq, hd] (rope applied), ``k_pages``/``v_pages``:
-    [n_pages, page_len, n_kv, hd], ``block_table``: [B, nb] arena page ids,
-    ``positions``: [B]. Routes to the Pallas kernel on TPU (or when
-    ``interpret=True`` — the CPU correctness path for tests); misaligned
-    shapes and plain CPU calls take the blocked jnp oracle. Returns the
-    f32 attention context [B, nq, hd] (pre-``wo``)."""
-    use_pallas = _ON_TPU if interpret is None else bool(interpret)
-    interp = (not _ON_TPU) if interpret is None else bool(interpret)
-    hd = q.shape[-1]
-    plen = k_pages.shape[1]
-    if not use_pallas or hd % 128 or plen % 8 or q.shape[1] % k_pages.shape[2]:
-        return ref.paged_attention_ref(q, k_pages, v_pages, block_table,
-                                       positions)
+    [n_pages, n_kv, page_len, hd], ``block_table``: [B, nb] arena page ids,
+    ``positions``: [B]. Returns the attention context [B, nq, hd] in
+    ``q.dtype`` (pre-``wo``)."""
+    interp = not on_tpu() if interpret is None else bool(interpret)
     return _pa.paged_attention(q, k_pages, v_pages, block_table, positions,
                                interpret=interp)
 
@@ -267,7 +318,6 @@ def paged_attention_op(q, k_pages, v_pages, block_table, positions, *,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def dequant_matmul_op(codes, scales, w, *, interpret: bool | None = None):
     """Fused dequant + up-proj. codes: [..., N] int8 -> [..., D] bf16."""
-    interp = (not _ON_TPU) if interpret is None else interpret
     lead = codes.shape[:-1]
     M = 1
     for s in lead:
@@ -277,7 +327,10 @@ def dequant_matmul_op(codes, scales, w, *, interpret: bool | None = None):
     s2 = scales.reshape(M, 1)
     bm = _pick_block(M, 128)
     bd = _pick_block(D, 512)
+    interp = not on_tpu() if interpret is None else interpret
     if M % bm or D % bd or N % 128:
+        if interpret is None:
+            _note_fallback("dequant_matmul", f"M={M} N={N} D={D}")
         y = ref.dequant_matmul_ref(c2, s2, w)
     else:
         y = _dq.dequant_matmul(c2, s2, w, block_m=bm, block_d=bd,
@@ -300,12 +353,14 @@ def rglru_scan_op(a, b, h0=None, *, interpret: bool | None = None):
     either way. Routes to the Pallas kernel on TPU (or ``interpret=True``
     for tests); CPU and non-block-multiple S/D take the jnp reference.
     """
-    use_pallas = _ON_TPU if interpret is None else bool(interpret)
-    interp = (not _ON_TPU) if interpret is None else bool(interpret)
     B, S, D = a.shape
     # MXU-sane tiles only: sublane-multiple time blocks, lane-multiple
     # feature blocks — anything else takes the reference
-    if not use_pallas or S % 8 or D % 128:
+    use_pallas, interp = _route(
+        "rglru_scan", interpret,
+        f"S={S} D={D} not multiples of (8, 128)" if S % 8 or D % 128
+        else "")
+    if not use_pallas:
         return ref.rglru_scan_ref(a, b, h0)
     if h0 is not None:
         b = b.at[:, 0, :].add(a[:, 0, :] * h0.astype(jnp.float32))

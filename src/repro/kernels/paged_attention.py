@@ -2,24 +2,35 @@
 block-table-indexed page arena.
 
 The paged pool stores every slot's KV rows in ``page_len``-row pages of a
-global arena; a per-slot block table maps logical row ``t`` to arena page
-``bt[b, t // page_len]``. Dense decode attention gathers the whole logical
-cache per step; here the grid walks (sequence, page) and the scalar-prefetch
-block table drives the K/V BlockSpec index maps, so each grid step streams
-exactly ONE page of K/V into VMEM — never a materialized
-``[B, nb * page_len, ...]`` gather — and pages entirely past a sequence's
-position are skipped by a ``pl.when`` guard (their index maps still clamp to
-a valid page id, the pool's reserved scratch page for short sequences).
+global arena laid out ``[n_pages, n_kv, page_len, hd]``: one page of one
+kv head is a ``[page_len, hd]`` tile, so a page is a stack of ``n_kv``
+whole tiles and the kernel never relayouts it. A per-slot block table maps
+logical row ``t`` to arena page ``bt[b, t // page_len]``. Dense decode
+attention gathers the whole logical cache per step; here the grid walks
+(sequence, page) and the scalar-prefetch block table drives the K/V
+BlockSpec index maps, so each grid step streams exactly ONE page of K/V
+into VMEM — never a materialized ``[B, nb * page_len, ...]`` gather — and
+pages entirely past a sequence's position are skipped by a ``pl.when``
+guard (their index maps still clamp to a valid page id, the pool's reserved
+scratch page for short sequences).
+
+Inside a page every kv head's ``g = nq / n_kv`` query heads are one 2-D
+problem: scores ``[g, hd] x [page_len, hd]^T`` and context
+``[g, page_len] x [page_len, hd]`` are plain MXU dots in the model dtype
+with f32 accumulation — no batched 3-D contraction, no head repeat.
 
 Grid: (B, nb) with the page dimension innermost, so each sequence's online
-softmax (m / l / acc in VMEM scratch, f32) completes before its epilogue.
-The oracle ``ref.paged_attention_ref`` mirrors the blocked computation
-op-for-op; interpret mode is pinned **bit-for-bit in sub-f32 dtypes**
-(bf16 — the ``q.dtype`` rounding barriers quantize away fusion noise,
-exactly like the boundary kernel) and to a few f32 ulp otherwise: XLA may
-rematerialize the interpreted kernel body with different FMA fusion than
-the oracle's op-by-op eager execution, which f32 barriers cannot quantize
-away (they are no-op casts).
+softmax (m / l / acc in VMEM scratch, f32, one row per query head) completes
+before its epilogue. The oracle ``ref.paged_attention_ref`` mirrors the
+blocked computation op-for-op; interpret mode is pinned **bit-for-bit in
+sub-f32 dtypes** (bf16 — the ``q.dtype`` rounding barriers quantize away
+fusion noise, exactly like the boundary kernel) and to a few f32 ulp
+otherwise: XLA may rematerialize the interpreted kernel body with different
+FMA fusion than the oracle's op-by-op eager execution, which f32 barriers
+cannot quantize away (they are no-op casts).
+
+``page_len`` must be a multiple of the model dtype's sublane tile (16 rows
+for bf16, 8 for f32) — ``ops.paged_kernel_eligible``.
 """
 from __future__ import annotations
 
@@ -31,14 +42,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.boundary_mixed import mxu_precision
+
 NEG_INF = -1e30
+
+#: contract the last dim of both operands: ``[g, hd] x [plen, hd] -> [g, plen]``
+_NT = (((1,), (1,)), ((), ()))
 
 
 def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-            acc_scr, *, nb: int, plen: int, g: int, scale: float):
+            acc_scr, *, nb: int, plen: int, n_kv: int, scale: float):
     b = pl.program_id(0)
     j = pl.program_id(1)
     pos_b = pos_ref[b]
+    dt = q_ref.dtype
+    prec = mxu_precision(dt)
+
+    def barrier(x):
+        # explicit rounding barriers at the score and probability hand-offs
+        # (same trick as the boundary kernel's GEMM chunks): the q-dtype
+        # casts pin compiled, interpret, and oracle paths bit-for-bit by
+        # quantizing away fusion/FMA rounding differences
+        return x.astype(dt).astype(jnp.float32)
 
     @pl.when(j == 0)
     def _init():
@@ -50,33 +75,26 @@ def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     # past the current position (page 0 always runs: row 0 <= pos)
     @pl.when(j * plen <= pos_b)
     def _page():
-        qf = q_ref[0].astype(jnp.float32)                    # [nq, hd]
-        kf = jnp.repeat(k_ref[0].astype(jnp.float32), g, 1)  # [plen, nq, hd]
-        vf = jnp.repeat(v_ref[0].astype(jnp.float32), g, 1)
-        # explicit rounding barriers at the score and probability hand-offs
-        # (same trick as the boundary kernel's GEMM chunks): the q-dtype
-        # casts pin compiled, interpret, and oracle paths bit-for-bit by
-        # quantizing away fusion/FMA rounding differences
-        s = (jnp.einsum("nh,tnh->nt", qf, kf) * scale
-             ).astype(q_ref.dtype).astype(jnp.float32)       # [nq, plen]
         t_abs = j * plen + jax.lax.broadcasted_iota(jnp.int32, (1, plen), 1)
-        s = jnp.where(t_abs <= pos_b, s, NEG_INF)
-        m_old = m_scr[...]                                   # [1, nq]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1)[None, :])
-        p = jnp.exp(s - m_new[0][:, None]
-                    ).astype(q_ref.dtype).astype(jnp.float32)  # [nq, plen]
-        corr = jnp.exp(m_old - m_new
-                       ).astype(q_ref.dtype).astype(jnp.float32)
-        m_scr[...] = m_new
-        l_scr[...] = (l_scr[...] * corr).astype(q_ref.dtype).astype(
-            jnp.float32) + jnp.sum(p, axis=-1)[None, :]
-        acc_scr[...] = (acc_scr[...] * corr[0][:, None]).astype(
-            q_ref.dtype).astype(jnp.float32) + jnp.einsum(
-            "nt,tnh->nh", p, vf).astype(q_ref.dtype).astype(jnp.float32)
+        for h in range(n_kv):                      # static: one kv group
+            s = barrier(jax.lax.dot_general(
+                q_ref[0, h], k_ref[0, h], _NT, precision=prec,
+                preferred_element_type=jnp.float32) * scale)   # [g, plen]
+            s = jnp.where(t_abs <= pos_b, s, NEG_INF)
+            m_old = m_scr[h]                                   # [g, 1]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+            p = barrier(jnp.exp(s - m_new))                    # [g, plen]
+            corr = barrier(jnp.exp(m_old - m_new))
+            m_scr[h] = m_new
+            l_scr[h] = barrier(l_scr[h] * corr) + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_scr[h] = barrier(acc_scr[h] * corr) + barrier(jnp.dot(
+                p.astype(dt), v_ref[0, h], precision=prec,
+                preferred_element_type=jnp.float32))           # [g, hd]
 
     @pl.when(j == nb - 1)
     def _epilogue():
-        o_ref[0] = (acc_scr[...] / l_scr[0][:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pages, v_pages, block_table, positions, *,
@@ -84,41 +102,46 @@ def paged_attention(q, k_pages, v_pages, block_table, positions, *,
     """Paged one-token GQA decode attention.
 
     q: [B, nq, hd] (rope already applied), ``k_pages``/``v_pages``:
-    [n_pages, page_len, n_kv, hd] arenas with the current token's row
+    [n_pages, n_kv, page_len, hd] arenas with the current token's row
     already written, ``block_table``: [B, nb] int32 arena page ids,
     ``positions``: [B] int32 absolute positions. Every page id must be a
     valid arena index (the pool guarantees this — unallocated table entries
-    point at the reserved scratch page). Returns the attention context
-    [B, nq, hd] in ``q.dtype`` (pre-``wo``).
+    point at the reserved scratch page). Query head ``i`` reads kv head
+    ``i // (nq / n_kv)``. Returns the attention context [B, nq, hd] in
+    ``q.dtype`` (pre-``wo``).
     """
     B, nq, hd = q.shape
-    n_pages, plen, n_kv, hd2 = k_pages.shape
+    n_pages, n_kv, plen, hd2 = k_pages.shape
     assert hd == hd2 and nq % n_kv == 0, (q.shape, k_pages.shape)
     nb = block_table.shape[1]
     g = nq // n_kv
+    qg = q.reshape(B, n_kv, g, hd)                 # kv-group-major heads
 
+    page = pl.BlockSpec((1, n_kv, plen, hd),
+                        lambda b, j, bt, pos: (bt[b, j], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((1, nq, hd), lambda b, j, bt, pos: (b, 0, 0)),
-            pl.BlockSpec((1, plen, n_kv, hd),
-                         lambda b, j, bt, pos: (bt[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, plen, n_kv, hd),
-                         lambda b, j, bt, pos: (bt[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, n_kv, g, hd), lambda b, j, bt, pos: (b, 0, 0, 0)),
+            page,
+            page,
         ],
-        out_specs=pl.BlockSpec((1, nq, hd), lambda b, j, bt, pos: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, n_kv, g, hd),
+                               lambda b, j, bt, pos: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((1, nq), jnp.float32),      # running max
-            pltpu.VMEM((1, nq), jnp.float32),      # running denominator
-            pltpu.VMEM((nq, hd), jnp.float32),     # context accumulator
+            pltpu.VMEM((n_kv, g, 1), jnp.float32),     # running max
+            pltpu.VMEM((n_kv, g, 1), jnp.float32),     # running denominator
+            pltpu.VMEM((n_kv, g, hd), jnp.float32),    # context accumulator
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_kernel, nb=nb, plen=plen, g=g,
+    out = pl.pallas_call(
+        functools.partial(_kernel, nb=nb, plen=plen, n_kv=n_kv,
                           scale=1.0 / math.sqrt(hd)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nq, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, n_kv, g, hd), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(block_table.astype(jnp.int32), positions.astype(jnp.int32),
-      q, k_pages, v_pages)
+      qg, k_pages, v_pages)
+    return out.reshape(B, nq, hd)

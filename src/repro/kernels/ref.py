@@ -112,48 +112,52 @@ def paged_attention_ref(q, k_pages, v_pages, block_table, positions):
     Pallas kernel is pinned bit-for-bit against it in interpret mode for
     sub-f32 dtypes (bf16); f32 matches to a few ulp (the barriers are no-op
     casts there and cannot quantize away XLA's fusion freedom).
-    q: [B, nq, hd]; ``k_pages``/``v_pages``: [n_pages, page_len, n_kv, hd];
+    q: [B, nq, hd]; ``k_pages``/``v_pages``: [n_pages, n_kv, page_len, hd];
     ``block_table``: [B, nb]; ``positions``: [B] (concrete host values —
     they steer the python page loop). Returns [B, nq, hd] in ``q.dtype``.
-    Test-scale only (python loop over sequences and pages).
+    Test-scale only (python loop over sequences, pages and kv heads).
     """
     import math
 
     NEG_INF = -1e30
     B, nq, hd = q.shape
-    plen = k_pages.shape[1]
-    n_kv = k_pages.shape[2]
+    n_kv, plen = k_pages.shape[1], k_pages.shape[2]
     g = nq // n_kv
     nb = block_table.shape[1]
     scale = 1.0 / math.sqrt(hd)
     dt = q.dtype
+
+    def barrier(x):
+        return x.astype(dt).astype(jnp.float32)
+
     outs = []
     for b in range(B):
         pos_b = int(positions[b])
-        m = jnp.full((1, nq), NEG_INF, jnp.float32)
-        l = jnp.zeros((1, nq), jnp.float32)
-        acc = jnp.zeros((nq, hd), jnp.float32)
-        qf = q[b].astype(jnp.float32)
-        for j in range(nb):
-            if j * plen > pos_b:
-                continue
-            page = block_table[b, j]
-            kf = jnp.repeat(k_pages[page].astype(jnp.float32), g, 1)
-            vf = jnp.repeat(v_pages[page].astype(jnp.float32), g, 1)
-            s = (jnp.einsum("nh,tnh->nt", qf, kf) * scale
-                 ).astype(dt).astype(jnp.float32)
-            t_abs = j * plen + jnp.arange(plen)[None, :]
-            s = jnp.where(t_abs <= pos_b, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1)[None, :])
-            p = jnp.exp(s - m_new[0][:, None]).astype(dt).astype(jnp.float32)
-            corr = jnp.exp(m - m_new).astype(dt).astype(jnp.float32)
-            m = m_new
-            l = (l * corr).astype(dt).astype(jnp.float32) \
-                + jnp.sum(p, axis=-1)[None, :]
-            acc = (acc * corr[0][:, None]).astype(dt).astype(jnp.float32) \
-                + jnp.einsum("nt,tnh->nh", p, vf).astype(dt).astype(
-                    jnp.float32)
-        outs.append((acc / l[0][:, None]).astype(dt))
+        qg = q[b].reshape(n_kv, g, hd)
+        heads = []
+        for h in range(n_kv):
+            m = jnp.full((g, 1), NEG_INF, jnp.float32)
+            l = jnp.zeros((g, 1), jnp.float32)
+            acc = jnp.zeros((g, hd), jnp.float32)
+            for j in range(nb):
+                if j * plen > pos_b:
+                    continue
+                page = block_table[b, j]
+                s = barrier(jnp.dot(qg[h], k_pages[page, h].T,
+                                    preferred_element_type=jnp.float32)
+                            * scale)
+                t_abs = j * plen + jnp.arange(plen)[None, :]
+                s = jnp.where(t_abs <= pos_b, s, NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                p = barrier(jnp.exp(s - m_new))
+                corr = barrier(jnp.exp(m - m_new))
+                m = m_new
+                l = barrier(l * corr) + jnp.sum(p, axis=-1, keepdims=True)
+                acc = barrier(acc * corr) + barrier(jnp.dot(
+                    p.astype(dt), v_pages[page, h],
+                    preferred_element_type=jnp.float32))
+            heads.append((acc / l).astype(dt))
+        outs.append(jnp.concatenate(heads, axis=0))
     return jnp.stack(outs)
 
 

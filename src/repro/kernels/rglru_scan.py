@@ -27,16 +27,16 @@ def _kernel(a_ref, b_ref, h_ref, carry_ref, *, block_s: int):
     def _reset():
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    a = a_ref[0]                       # [BS, BD]
-    b = b_ref[0]
-
     def step(t, h):
-        h = a[t] * h + b[t]
-        h_ref[0, t, :] = h
+        # one time step straight through the refs: a [1, BD] row load per
+        # operand and a row store (Mosaic has no dynamic_slice of a loaded
+        # value, so the loop index must address the ref)
+        row = pl.ds(t, 1)
+        h = a_ref[0, row, :] * h + b_ref[0, row, :]
+        h_ref[0, row, :] = h
         return h
 
-    h = jax.lax.fori_loop(0, block_s, step, carry_ref[0])
-    carry_ref[0, :] = h
+    carry_ref[...] = jax.lax.fori_loop(0, block_s, step, carry_ref[...])
 
 
 def rglru_scan(a, b, *, block_s: int = 256, block_d: int = 512,
@@ -58,4 +58,5 @@ def rglru_scan(a, b, *, block_s: int = 256, block_d: int = 512,
         out_shape=jax.ShapeDtypeStruct((B, S, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
         interpret=interpret,
+        name="rglru_scan",
     )(a, b)

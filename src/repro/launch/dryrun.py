@@ -40,7 +40,7 @@ from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
 from repro.core import split as SP
 from repro.data.tokens import token_batch_shapes
 from repro.launch import analytic, roofline
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.models import sharding
 from repro.models import transformer as T
 from repro.training import loop as train_loop
@@ -201,7 +201,7 @@ def run_one(arch: str, shape: str, *, multi_pod: bool = False,
     t0 = time.time()
     step, args = build_step(cfg, sc, mesh, variant, seq_shard, act_policy,
                             tp_scope, moe_ep, kv_bits)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = step.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

@@ -1,38 +1,52 @@
-"""Production mesh construction (TPU v5e pods).
+"""Mesh construction: the one place the program builds a ``Mesh``.
 
-A FUNCTION, not a module-level constant, so importing this module never
+FUNCTIONS, not module-level constants, so importing this module never
 touches jax device state (the dry-run must set XLA_FLAGS before first init).
+
+Every mesh carries ``Auto`` axis types. ``jax.make_mesh`` defaults to
+``Explicit`` axes, under which every gather, dot and scan of the model would
+have to name its output sharding; the sharding rules in
+``models/sharding.py`` are written for GSPMD propagation instead.
 """
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 
-def mesh_context(mesh):
-    """Enter ``mesh`` as the ambient mesh, across JAX versions.
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Optional[Sequence] = None) -> Mesh:
+    """A mesh of ``shape`` over ``axes`` with ``Auto`` axis types.
 
-    Newer JAX exposes ``jax.set_mesh``; on older releases
-    ``jax.sharding.Mesh`` is itself the context manager.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    ``devices``: use exactly these devices, in this order (a serving
+    replica's contiguous block); ``None`` lets ``jax.make_mesh`` lay the
+    first ``prod(shape)`` devices out for the physical topology."""
+    types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=types)
+    arr = np.asarray(list(devices), dtype=object).reshape(tuple(shape))
+    return Mesh(arr, tuple(axes), axis_types=types)
 
 
-def make_production_mesh(*, multi_pod: bool = False):
+def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """Single pod: 16x16 = 256 chips (data, model).
     Multi-pod: 2 pods x 256 = 512 chips (pod, data, model)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
-def make_host_mesh(pod: int = 1, data: int = 2, model: int = 2):
-    """Small mesh for CPU integration tests (requires forced host devices)."""
-    if pod > 1:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+def make_host_mesh(model_parallel: int = 1) -> Mesh:
+    """(data, model) mesh over every device of this host (one CPU device
+    gives a 1x1 mesh)."""
+    n = jax.device_count()
+    if n % model_parallel != 0:
+        raise ValueError(f"{n} devices not divisible by mp={model_parallel}")
+    return make_mesh((n // model_parallel, model_parallel),
+                     ("data", "model"))
 
 
 # TPU v5e hardware constants (per chip) used by the roofline analysis

@@ -39,6 +39,7 @@ from repro.core.channel import (Channel, ChannelConfig, FleetChannel,
 from repro.data.lumos5g import capacity_traces_bps
 from repro.core.orchestrator import AppRequirement, ModeProfile, Orchestrator
 from repro.data import tokens
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.models.sharding import serving_mesh
 from repro.serving import (HANDOVER_POLICIES, PLACEMENTS,
@@ -125,12 +126,13 @@ def run_continuous(args, cfg, params, tel=None):
     with Stopwatch() as sw:
         done = eng.run(reqs)
     st = eng.stats()
+    eng.close()
     return {
         "engine": "continuous",
         "n_slots": args.n_slots,
         "decode_tok_per_s": round(
             st["decode_tokens"] / max(sw.seconds, 1e-9), 1),
-        "per_request": [s.result() for s in done[:4]],
+        "per_request": [s.result() for s in done],
         **_latency_section(tel),
         **st,
     }
@@ -366,12 +368,16 @@ def main(argv=None):
     ap.add_argument("--json-out", default=None)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     print(f"== launch.serve {args.arch} "
           f"({'reduced' if args.reduced else 'FULL'}) "
           f"engine={args.engine} requests={args.requests} "
           f"prompt={args.prompt_len} gen={args.gen} ==")
-    params = SP.init_split_params(jax.random.PRNGKey(0), cfg)
+    # one jitted init: eager init would materialize each stacked weight in
+    # f32 (and its random bits) before the cast to the model dtype
+    params = jax.jit(SP.init_split_params, static_argnums=1)(
+        jax.random.PRNGKey(0), cfg)
     if args.ckpt:
         params = checkpoint.restore(args.ckpt, params)
         print(f"loaded weights from {args.ckpt}")

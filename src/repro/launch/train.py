@@ -34,20 +34,12 @@ from repro.configs.base import ModelConfig, TrainConfig
 from repro.core import cascade as CC
 from repro.core import split as SP
 from repro.data import tokens
-from repro.launch.mesh import mesh_context
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.models import sharding
 from repro.training import checkpoint
 from repro.training import loop as L
 from repro.training import optimizer as opt
-
-
-def make_host_mesh(model_parallel: int = 1):
-    """Mesh over the real devices: (data, model)."""
-    n = jax.device_count()
-    if n % model_parallel != 0:
-        raise ValueError(f"{n} devices not divisible by mp={model_parallel}")
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
 
 
 def sharded_init(cfg: ModelConfig, mesh, seed: int = 0):
@@ -59,7 +51,7 @@ def sharded_init(cfg: ModelConfig, mesh, seed: int = 0):
     out_sh = jax.tree.map(lambda sp: NamedSharding(mesh, sp), specs)
     init = jax.jit(lambda k: SP.init_split_params(k, cfg),
                    out_shardings=out_sh)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         return init(jax.random.PRNGKey(seed)), specs
 
 
@@ -71,7 +63,7 @@ def run_phase(params, cfg, tcfg, mesh, specs, data_fn, *, steps, mode,
     jitted = jax.jit(step_fn, donate_argnums=(0, 1) if donate else ())
     hist = []
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         for s in range(steps):
             batch = {k: jnp.asarray(v) for k, v in data_fn(s).items()}
             params, opt_state, m = jitted(params, opt_state, batch)
@@ -104,6 +96,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     mesh = make_host_mesh(args.mp)
     print(f"== launch.train {args.arch} ({'reduced' if args.reduced else 'FULL'}) "
@@ -131,7 +124,7 @@ def main(argv=None):
             return L.make_eval_step(cfg, mode=mode)(p, b)
 
         n_modes = cfg.split.n_modes
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             params, hist = CC.train_cascade(
                 params, loss_fn,
                 lambda s: {k: jnp.asarray(v) for k, v in data_fn(s).items()},

@@ -256,7 +256,7 @@ def paged_prefill_attention(p, x, positions, arena, block_table, *,
     into a paged arena instead of ``mod(pos, cache_len)`` rolling slots.
 
     ``arena``: per-layer ``{"k","v"}`` leaves of shape
-    ``[n_pages, page_len, n_kv, hd]`` shared by every slot; ``block_table``:
+    ``[n_pages, n_kv, page_len, hd]`` shared by every slot; ``block_table``:
     ``[B, nb]`` page ids, one row per sequence, covering at least
     ``ceil(length / page_len)`` pages. Pad rows (``s >= lengths[b]``) get an
     out-of-bounds page index and are dropped by the scatter, mirroring the
@@ -264,7 +264,7 @@ def paged_prefill_attention(p, x, positions, arena, block_table, *,
     the output is identical to :func:`prefill_attention` on the same prompt.
     """
     B, S = x.shape[:2]
-    n_pages, plen = arena["k"].shape[:2]
+    n_pages, plen = arena["k"].shape[0], arena["k"].shape[2]
     nb = block_table.shape[1]
     q, k, v = _project_qkv(p, x, n_q, n_kv, hd)
     q = apply_rope(q, positions, rope_theta)
@@ -284,8 +284,8 @@ def paged_prefill_attention(p, x, positions, arena, block_table, *,
     pg = jnp.where(valid,
                    block_table[jnp.arange(B)[:, None], pg_ix], n_pages)
     row = jnp.mod(positions, plen)
-    new_arena = {"k": arena["k"].at[pg, row].set(k, mode="drop"),
-                 "v": arena["v"].at[pg, row].set(v, mode="drop")}
+    new_arena = {"k": arena["k"].at[pg, :, row].set(k, mode="drop"),
+                 "v": arena["v"].at[pg, :, row].set(v, mode="drop")}
     return out.astype(x.dtype) @ p["wo"]["w"], new_arena
 
 
@@ -294,7 +294,7 @@ def paged_decode_attention(p, x, arena, block_table, cur_pos, *, n_q: int,
     """One-token decode against a paged arena through a block table.
 
     x: [B, 1, d]; cur_pos: [B] per-sequence absolute positions; ``arena``
-    leaves ``[n_pages, page_len, n_kv, hd]``; ``block_table`` ``[B, nb]``.
+    leaves ``[n_pages, n_kv, page_len, hd]``; ``block_table`` ``[B, nb]``.
     The caller guarantees the page holding row ``cur_pos`` is allocated for
     every live sequence; idle sequences carry all-zero block-table rows, so
     their drifting writes land in the reserved scratch page 0 (never read
@@ -305,7 +305,7 @@ def paged_decode_attention(p, x, arena, block_table, cur_pos, *, n_q: int,
     Returns (out [B,1,d], updated arena).
     """
     B = x.shape[0]
-    plen = arena["k"].shape[1]
+    plen = arena["k"].shape[2]
     nb = block_table.shape[1]
     q, k, v = _project_qkv(p, x, n_q, n_kv, hd)
     pos = jnp.asarray(cur_pos, dtype=jnp.int32).reshape(B, 1)
@@ -314,17 +314,20 @@ def paged_decode_attention(p, x, arena, block_table, cur_pos, *, n_q: int,
 
     pg = block_table[jnp.arange(B), jnp.clip(pos[:, 0] // plen, 0, nb - 1)]
     row = jnp.mod(pos[:, 0], plen)
-    new_arena = {"k": arena["k"].at[pg, row].set(k[:, 0]),
-                 "v": arena["v"].at[pg, row].set(v[:, 0])}
+    new_arena = {"k": arena["k"].at[pg, :, row].set(k[:, 0]),
+                 "v": arena["v"].at[pg, :, row].set(v[:, 0])}
 
     from repro.kernels import ops as K
-    if K.paged_kernel_eligible(n_q=n_q, n_kv=n_kv, hd=hd, page_len=plen):
+    if K.paged_kernel_eligible(n_q=n_q, n_kv=n_kv, hd=hd, page_len=plen,
+                               dtype=q.dtype):
         ctx = K.paged_attention_op(q[:, 0], new_arena["k"], new_arena["v"],
                                    block_table, pos[:, 0])
         out = ctx.reshape(B, 1, n_q * hd).astype(x.dtype)
     else:
-        ck = new_arena["k"][block_table].reshape(B, nb * plen, n_kv, hd)
-        cv = new_arena["v"][block_table].reshape(B, nb * plen, n_kv, hd)
+        def logical(a):                # [B, nb, n_kv, plen, hd] -> rows
+            return a[block_table].swapaxes(2, 3).reshape(
+                B, nb * plen, n_kv, hd)
+        ck, cv = logical(new_arena["k"]), logical(new_arena["v"])
         scores = _gqa_scores(q, ck) / math.sqrt(hd)   # [B,kv,G,1,T]
         t = jnp.arange(nb * plen)
         n_fill = jnp.minimum(pos[:, 0] + 1, nb * plen)

@@ -20,29 +20,19 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
+
+
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check: bool = False):
-    """``jax.shard_map`` across JAX versions.
-
-    Newer JAX exposes it at top level with ``check_vma`` / ``axis_names``
-    (the set of *manual* axes); older releases have
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep`` / ``auto``
-    (the complementary set of axes left automatic). Partially-manual
-    ``auto`` subgroups CHECK-fail inside old XLA's SPMD partitioner, so the
-    legacy path runs fully manual instead: axes the caller wanted automatic
-    must then not appear in any spec, and their compute stays local and
-    replicated — numerically identical, just without GSPMD re-sharding.
-    """
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return native(f, **kw)
-    from jax.experimental.shard_map import shard_map as legacy
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check)
+    """``jax.shard_map`` with this repo's defaults: ``axis_names`` is the
+    set of *manual* axes (all of them when None; the rest stay automatic),
+    and the varying-manual-axes check is off unless ``check``."""
+    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+              check_vma=check)
+    if axis_names is not None:
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -311,8 +301,7 @@ def serving_mesh(dp: int, mp: int = 1, *, devices=None) -> Mesh:
             f"mesh ({dp} x {mp}) needs {need} devices, only "
             f"{len(devices)} available — on CPU, set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N")
-    arr = np.array(devices[:need], dtype=object).reshape(dp, mp)
-    return Mesh(arr, ("dp", "mp"))
+    return make_mesh((dp, mp), ("dp", "mp"), devices=devices[:need])
 
 
 def _rename_spec(spec: P, mapping: Dict[Optional[str], Optional[str]]) -> P:
@@ -334,47 +323,45 @@ def serving_param_pspecs(params, mesh: Mesh, **kwargs):
     replicated (every dp row serves every slot, so weights replicate over
     ``dp``). Reuses the training ``_PARAM_RULES`` via a proxy mesh with the
     training axis names, then renames ``model -> mp`` / drops ``data``."""
-    proxy = Mesh(mesh.devices, ("data", "model"))
+    proxy = make_mesh(mesh.devices.shape, ("data", "model"),
+                      devices=mesh.devices.flat)
     specs = param_pspecs(params, proxy, **kwargs)
     ren = {"data": None, "model": "mp"}
     return jax.tree.map(lambda s: _rename_spec(s, ren), specs,
                         is_leaf=lambda x: isinstance(x, P))
 
 
-def _pool_spec(path, shape, mesh: Mesh, slot_axis: int) -> P:
+def _pool_spec(path, shape, mesh: Mesh, slot_axis: int,
+               paged: bool = False) -> P:
     spec = [None] * len(shape)
     if len(shape) > slot_axis:
         spec[slot_axis] = "dp"
     last = _path_str(path).split("/")[-1]
     if last in _KV_LEAF_NAMES and len(shape) == slot_axis + 4:
-        # [..., slots, T, n_kv, head_dim] — head groups over mp
-        spec[slot_axis + 2] = "mp"
+        # dense [..., slots, T, n_kv, head_dim] or paged
+        # [..., pages, n_kv, page_len, head_dim] — head groups over mp
+        spec[slot_axis + (1 if paged else 2)] = "mp"
     return _fit_spec(P(*spec), shape, mesh)
 
 
-def pool_pspecs(states, mesh: Mesh, *, slot_axis: int):
+def pool_pspecs(states, mesh: Mesh, *, slot_axis: int, paged: bool = False):
     """Slot-pool specs: slot axis over ``dp``, KV head groups over ``mp``;
     non-dividing dims fall back to replicated (``_fit_spec``). ``slot_axis``
     is 1 for stacked homogeneous states ``[L, S, ...]`` and the paged arena
-    ``[L, pages, ...]`` (pages are that pool's slot axis), 0 for
-    heterogeneous per-layer states ``[S, ...]``."""
+    ``[L, pages, ...]`` (pages are that pool's slot axis; ``paged`` puts
+    its head axis right after them), 0 for heterogeneous per-layer states
+    ``[S, ...]``."""
     return jax.tree_util.tree_map_with_path(
-        lambda p, leaf: _pool_spec(p, leaf.shape, mesh, slot_axis), states)
+        lambda p, leaf: _pool_spec(p, leaf.shape, mesh, slot_axis, paged),
+        states)
 
 
-def pool_shardings(states, mesh: Mesh, *, slot_axis: int):
-    """``NamedSharding`` tree matching ``pool_pspecs`` (handy for
-    ``jax.jit`` in_shardings / ``device_put``)."""
-    return jax.tree_util.tree_map_with_path(
-        lambda p, leaf: NamedSharding(
-            mesh, _pool_spec(p, leaf.shape, mesh, slot_axis)), states)
-
-
-def shard_pool(states, mesh: Mesh, *, slot_axis: int):
+def shard_pool(states, mesh: Mesh, *, slot_axis: int, paged: bool = False):
     """Place a slot-pool state tree onto the serving mesh."""
-    return jax.tree_util.tree_map_with_path(
-        lambda p, leaf: jax.device_put(leaf, NamedSharding(
-            mesh, _pool_spec(p, leaf.shape, mesh, slot_axis))), states)
+    specs = pool_pspecs(states, mesh, slot_axis=slot_axis, paged=paged)
+    return jax.tree.map(
+        lambda leaf, s: jax.device_put(leaf, NamedSharding(mesh, s)),
+        states, specs)
 
 
 def constrain_batch(x, mesh: Optional[Mesh], *, axis: int = 0):

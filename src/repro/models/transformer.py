@@ -285,6 +285,16 @@ def init_decode_state(cfg: ModelConfig, batch: int, cache_len: int,
                  for i in range(cfg.n_layers))
 
 
+def init_paged_state(cfg: ModelConfig, n_pages: int, page_len: int):
+    """Paged KV arena of a homogeneous full-attention arch: ``{"k","v"}``
+    leaves ``[L, n_pages, n_kv, page_len, hd]``. One page of one kv head is
+    one ``[page_len, hd]`` tile — the block ``kernels.paged_attention``
+    streams — where dense caches keep ``[..., T, n_kv, hd]``."""
+    shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_len, cfg.head_dim)
+    dt = model_dtype(cfg)
+    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+
+
 # ---------------------------------------------------------------------------
 # embedding / head
 # ---------------------------------------------------------------------------

@@ -502,18 +502,18 @@ class SlotPool:
 @functools.partial(jax.jit, static_argnums=(3,))
 def _gather_pages(arena, bt, used, plen: int):
     """Gather block-table pages into logical row order: arena leaves
-    ``[L, n_pages + 1, plen, ...]`` + table ``[n, nb]`` -> dense
-    ``[L, n, nb * plen, ...]`` blocks. Chunks at or past each row's
+    ``[L, n_pages + 1, n_kv, plen, hd]`` + table ``[n, nb]`` -> dense
+    ``[L, n, nb * plen, n_kv, hd]`` blocks. Chunks at or past each row's
     allocation (``used``) are zeroed — they point at the scratch page,
     whose contents are drifting-write junk."""
     nb = bt.shape[1]
     keep = jnp.arange(nb)[None, :] < used[:, None]        # [n, nb]
 
     def take(a):
-        g = a[:, bt]                                      # [L, n, nb, plen, *]
-        m = keep.reshape((1,) + keep.shape + (1,) * (g.ndim - 3))
-        g = jnp.where(m, g, 0)
-        return g.reshape(g.shape[:2] + (nb * g.shape[3],) + g.shape[4:])
+        g = a[:, bt]                              # [L, n, nb, n_kv, plen, hd]
+        g = jnp.where(keep[None, :, :, None, None, None], g, 0)
+        g = g.swapaxes(3, 4)                      # [L, n, nb, plen, n_kv, hd]
+        return g.reshape(g.shape[:2] + (nb * plen,) + g.shape[4:])
 
     return jax.tree.map(take, arena)
 
@@ -521,13 +521,14 @@ def _gather_pages(arena, bt, used, plen: int):
 @functools.partial(jax.jit, static_argnums=(4,))
 def _scatter_pages(arena, rows, bt, used, plen: int):
     """The inverse of :func:`_gather_pages`: scatter dense logical-row
-    blocks ``[L, n, nb * plen, ...]`` back through the block table; chunks
-    past a row's allocation get an out-of-bounds page index and drop."""
+    blocks ``[L, n, nb * plen, n_kv, hd]`` back through the block table;
+    chunks past a row's allocation get an out-of-bounds page index and
+    drop."""
     nb = bt.shape[1]
     keep = jnp.arange(nb)[None, :] < used[:, None]        # [n, nb]
 
     def put(a, r):
-        rc = r.reshape(r.shape[:2] + (nb, plen) + r.shape[3:])
+        rc = r.reshape(r.shape[:2] + (nb, plen) + r.shape[3:]).swapaxes(3, 4)
         pg = jnp.where(keep, bt, a.shape[1])
         return a.at[:, pg].set(rc, mode="drop")
 
@@ -539,12 +540,13 @@ class PagedPool:
     block tables, and a page free list.
 
     The arena holds ``n_pages + 1`` pages of ``page_len`` rows per leaf
-    (``[L, n_pages + 1, page_len, n_kv, hd]``); page 0 is the reserved
+    (``[L, n_pages + 1, n_kv, page_len, hd]``, ``T.init_paged_state``);
+    page 0 is the reserved
     scratch page — free slots carry all-zero block-table rows, so their
     drifting decode writes land there and are never read unmasked. Real
     pages are 1..n_pages. A slot's logical row ``t`` (== absolute position
     ``t``; full attention never wraps) lives at
-    ``arena[block_np[slot, t // page_len], t % page_len]``.
+    ``arena[block_np[slot, t // page_len], :, t % page_len]``.
 
     Admission-side accounting: ``commit_pages`` reserves a session's
     worst-case page count up front and ``pages_available`` subtracts every
@@ -564,7 +566,7 @@ class PagedPool:
     paged = True
 
     def __init__(self, cfg: ModelConfig, n_slots: int, cache_len: int, *,
-                 page_len: int = 8, n_pages: Optional[int] = None,
+                 page_len: int = 16, n_pages: Optional[int] = None,
                  mesh=None):
         if not (T.full_attention_arch(cfg) and cfg.homogeneous):
             raise ValueError(
@@ -584,9 +586,10 @@ class PagedPool:
         if mesh is not None:
             dp = mesh.shape["dp"]
             n_arena = -(-n_arena // dp) * dp
-        self.states = T.init_decode_state(cfg, n_arena, page_len)
+        self.states = T.init_paged_state(cfg, n_arena, page_len)
         if mesh is not None:
-            self.states = sharding.shard_pool(self.states, mesh, slot_axis=1)
+            self.states = sharding.shard_pool(self.states, mesh, slot_axis=1,
+                                              paged=True)
         self.positions = np.zeros(n_slots, np.int32)
         self._free = list(range(n_slots - 1, -1, -1))
         self.block_np = np.zeros((n_slots, self.n_pages), np.int32)
@@ -719,8 +722,8 @@ class PagedPool:
             jnp.asarray(self.pages_used[sl], jnp.int32), self.page_len)
 
     def read_pages(self, slot: int):
-        """A slot's ALLOCATED pages in block-table order — ``[L, nbu, plen,
-        ...]`` per leaf, the migration payload (pages only, no dense
+        """A slot's ALLOCATED pages in block-table order — ``[L, nbu, n_kv,
+        plen, hd]`` per leaf, the migration payload (pages only, no dense
         expansion, no scratch junk)."""
         nbu = max(int(self.pages_used[slot]), 1)
         bt = jnp.asarray(self.block_np[slot, :nbu], jnp.int32)
@@ -754,7 +757,7 @@ class ContinuousBatchingEngine:
                  host_loop: bool = False,
                  max_window: int = 16,
                  paged: Optional[bool] = None,
-                 page_len: int = 8,
+                 page_len: int = 16,
                  n_pages: Optional[int] = None,
                  mesh=None,
                  fused_tail: bool = True,

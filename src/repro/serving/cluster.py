@@ -128,8 +128,10 @@ class EdgeCluster:
 
     ``dp``/``mp`` give every replica its own ``(dp, mp)`` serving mesh on
     a disjoint contiguous device block (``devices`` overrides the global
-    ``jax.devices()`` order); both unset keeps the legacy single-device
-    replicas (``mesh=None`` engines).
+    ``jax.devices()`` order). With both unset, replica ``i`` gets a 1x1
+    mesh on device ``i`` when there are at least ``n_replicas`` devices
+    (and more than one); otherwise, and under an autoscaler, replicas are
+    ``mesh=None`` engines on the default device.
     """
 
     def __init__(self, params, cfg: ModelConfig, *, n_replicas: int = 2,
@@ -154,13 +156,13 @@ class EdgeCluster:
         if n_replicas < 1:
             raise ValueError("need at least one replica")
         meshes: List = [None] * n_replicas
+        devices = list(jax.devices() if devices is None else devices)
         if dp is not None or mp is not None:
             if autoscaler is not None:
                 raise ValueError(
                     "elastic scaling requires mesh-less replicas: a new "
                     "replica has no disjoint device block to claim")
             dp, mp = int(dp or 1), int(mp or 1)
-            devices = list(jax.devices() if devices is None else devices)
             per = dp * mp
             if n_replicas * per > len(devices):
                 raise ValueError(
@@ -170,6 +172,12 @@ class EdgeCluster:
                     "--xla_force_host_platform_device_count=N")
             meshes = [serving_mesh(dp, mp,
                                    devices=devices[i * per:(i + 1) * per])
+                      for i in range(n_replicas)]
+        elif autoscaler is None and 1 < len(devices) \
+                and n_replicas <= len(devices):
+            # one device per replica wherever there are enough: mesh-less
+            # engines would all run on the default device
+            meshes = [serving_mesh(1, 1, devices=[devices[i]])
                       for i in range(n_replicas)]
         self.cfg = cfg
         self.placement = placement

@@ -16,7 +16,7 @@ backhaul bytes, and tests measure both).
 Dense pools (``SlotPool``) snapshot via ``read_rows`` — the slot's full
 ``[L, 1, cache_len, ...]`` rows. Paged pools (``PagedPool``) ship only the
 session's **allocated pages**: ``PagedPool.read_pages`` gathers the slot's
-block-table entries into ``[L, n_pages_used, page_len, ...]`` blocks in
+block-table entries into ``[L, n_pages_used, n_kv, page_len, hd]`` blocks in
 block-table (= logical row) order, so the wire never carries the unused
 tail of the arena. Page *ids* don't cross the backhaul — the target
 allocates its own pages from its own free list and ``write_pages`` rebuilds

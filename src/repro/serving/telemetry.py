@@ -459,18 +459,14 @@ class Telemetry:
 @contextlib.contextmanager
 def profile_capture(profile_dir: Optional[str]):
     """Wrap a region in a ``jax.profiler`` trace when ``profile_dir`` is
-    set (the launcher's ``--profile-dir``); a no-op otherwise, and a
-    no-op (with a warning) when the profiler backend is unavailable."""
+    set (the launcher's ``--profile-dir``); a no-op otherwise. A profiler
+    that fails to start raises: a run asked to trace must not silently
+    run untraced."""
     if not profile_dir:
         yield
         return
     import jax
-    try:
-        jax.profiler.start_trace(profile_dir)
-    except Exception as e:                     # pragma: no cover - env dep
-        print(f"telemetry: jax.profiler unavailable ({e}); skipping")
-        yield
-        return
+    jax.profiler.start_trace(profile_dir)
     try:
         yield
     finally:

@@ -1,5 +1,7 @@
 """Pallas kernel validation: interpret-mode execution vs pure-jnp oracles
 across shape/dtype sweeps (per-kernel allclose, per the deliverable)."""
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -382,6 +384,30 @@ def test_rglru_scan_op_unaligned_falls_back():
         got = ops.rglru_scan_op(a, b, h0=h0, interpret=True)
         want = ref.rglru_scan_ref(a, b, h0)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_tpu_fallbacks_are_recorded(monkeypatch):
+    """On a TPU a dispatcher that must take the jnp reference for a shape
+    its kernel cannot tile records it in ``ops.FALLBACKS`` (what
+    ``chip_smoke.py`` fails on) instead of falling back silently; aligned
+    shapes and paged pages of whole bf16 tiles record nothing."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "FALLBACKS", collections.Counter())
+    a = jax.nn.sigmoid(jax.random.normal(KEY, (2, 13, 96)))
+    b = jax.random.normal(jax.random.PRNGKey(18), (2, 13, 96))
+    with pytest.warns(UserWarning, match="rglru_scan"):
+        got = ops.rglru_scan_op(a, b)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(ref.rglru_scan_ref(a, b)))
+    assert list(ops.FALLBACKS) == [("rglru_scan",
+                                    "S=13 D=96 not multiples of (8, 128)")]
+    assert ops.paged_kernel_eligible(n_q=16, n_kv=2, hd=128, page_len=16)
+    assert len(ops.FALLBACKS) == 1
+    with pytest.warns(UserWarning, match="paged_attention"):
+        assert not ops.paged_kernel_eligible(n_q=16, n_kv=2, hd=128,
+                                             page_len=8)
+    assert ("paged_attention",
+            "page [8, 128] is not whole bfloat16 tiles") in ops.FALLBACKS
 
 
 def test_ops_fallback_on_odd_shapes():

@@ -4,8 +4,10 @@ deployment calls with the full configs."""
 import json
 import os
 
+import jax
 import pytest
 
+from repro.launch import cache
 from repro.launch import serve as serve_launch
 from repro.launch import train as train_launch
 
@@ -49,3 +51,24 @@ def test_serve_launcher_policies(tmp_path):
     # the bottleneck mode must be strictly cheaper on the wire than raw
     assert st1["wire_bytes"] < st0["wire_bytes"]
     assert json.load(open(tmp_path / "dyn.json"))["policy"] == "orchestrator"
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """The entry points' compile cache: ``JAX_COMPILATION_CACHE_DIR`` wins
+    and is left to JAX; without it, one fixed gitignored path inside the
+    checkout."""
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(cache.ENV, str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        assert cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv(cache.ENV)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".jax_cache")
+        assert cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        ignored = open(os.path.join(repo, ".gitignore")).read().split()
+        assert ".jax_cache/" in ignored
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)

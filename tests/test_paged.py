@@ -73,8 +73,8 @@ def test_paged_attention_kernel_parity(B, nb, plen, n_kv, g, hd, dtype):
     n_pages = B * nb + 1
     keys = jax.random.split(jax.random.PRNGKey(7), 3)
     q = jax.random.normal(keys[0], (B, nq, hd)).astype(dtype)
-    kp = jax.random.normal(keys[1], (n_pages, plen, n_kv, hd)).astype(dtype)
-    vp = jax.random.normal(keys[2], (n_pages, plen, n_kv, hd)).astype(dtype)
+    kp = jax.random.normal(keys[1], (n_pages, n_kv, plen, hd)).astype(dtype)
+    vp = jax.random.normal(keys[2], (n_pages, n_kv, plen, hd)).astype(dtype)
     rng = np.random.default_rng(11)
     pos = rng.integers(0, nb * plen, size=B).astype(np.int32)
     bt = np.zeros((B, nb), np.int32)
@@ -268,7 +268,7 @@ def test_long_prompt_beyond_dense_cache(qwen, host_loop):
     cfg, params = qwen
     eng = ContinuousBatchingEngine(params, cfg, n_slots=3, cache_len=32,
                                    host_loop=host_loop)
-    assert eng.max_context == 96                 # 12 pages * 8 rows
+    assert eng.max_context == 96                 # 6 pages * 16 rows
     rng = np.random.default_rng(0)
     reqs = [Request(rid=0, prompt=rng.integers(
                 1, cfg.vocab_size, 50).astype(np.int32), max_new_tokens=8)]
@@ -359,7 +359,7 @@ def test_paged_inject_budget_refusal(qwen):
                                    host_loop=True)
     dst = ContinuousBatchingEngine(params, cfg, n_slots=2, cache_len=32,
                                    orchestrator=default_orchestrator(cfg),
-                                   n_pages=2)    # 16 rows < 4+20-1 worst
+                                   n_pages=1)    # 16 rows < 4+20-1 worst
     src.submit(Request(rid=0, prompt=_prompt(cfg, seed=2),
                        max_new_tokens=20, channel=_mobility(60)))
     for _ in range(3):

@@ -13,15 +13,15 @@ import functools
 import jax, jax.numpy as jnp
 from repro.configs import get_reduced
 from repro.core import split as S, pipeline as PL
-from repro.launch.mesh import mesh_context
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 
-mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
 cfg = get_reduced('stablelm-3b')
 params = S.init_split_params(jax.random.PRNGKey(0), cfg)
 tok = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, cfg.vocab_size)
 
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     # mode 0: pipeline == monolithic forward (bf16 tolerance)
     fn0 = jax.jit(functools.partial(PL.pipeline_forward, cfg=cfg, mesh=mesh,
                                     n_micro=4, mode=0))
@@ -81,7 +81,7 @@ print('PIPELINE_OK')
 def test_pipeline_two_pods():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                        capture_output=True, text=True, timeout=600)
     assert "PIPELINE_OK" in r.stdout, r.stdout[-2000:] + r.stderr[-2000:]

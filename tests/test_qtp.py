@@ -11,10 +11,10 @@ import jax, jax.numpy as jnp
 jax.config.update("jax_default_matmul_precision", "highest")
 from repro.configs import get_reduced
 from repro.core import split as S, qtp as QTP
-from repro.launch.mesh import mesh_context
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_mesh((2, 4), ('data', 'model'))
 
 for arch in ('stablelm-3b', 'granite-8b'):
     cfg = get_reduced(arch)
@@ -24,7 +24,7 @@ for arch in ('stablelm-3b', 'granite-8b'):
     tok = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,
                              cfg.vocab_size)
     ref, _ = T.forward(params, tok, cfg)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lg0 = jax.jit(lambda p, t: QTP.qtp_forward(
             p, t, cfg, mesh=mesh, bits=0))(params, tok)
         lg8 = jax.jit(lambda p, t: QTP.qtp_forward(
@@ -50,7 +50,7 @@ print('QTP OK')
 def test_qtp_matches_monolithic_forward():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stdout + r.stderr

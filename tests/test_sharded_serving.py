@@ -236,6 +236,20 @@ def test_pool_states_carry_dp_sharding(models):
 
 
 @NEED8
+def test_meshless_replicas_one_device_each(models):
+    """Without dp/mp, a host with enough devices gives each replica its own
+    device (a 1x1 mesh) instead of stacking every replica on device 0."""
+    cfg, params = models["qwen2.5-3b"]
+    cluster = EdgeCluster(params, cfg, n_replicas=4, n_slots=2,
+                          cache_len=16)
+    homes = [{d for leaf in jax.tree.leaves(r.params)
+              for d in leaf.devices()} for r in cluster.replicas]
+    cluster.close()
+    assert all(len(h) == 1 for h in homes)
+    assert len(set().union(*homes)) == 4
+
+
+@NEED8
 def test_paged_arena_padded_to_dp(models):
     """The paged arena's natural page count (n_pages+1, usually odd) is
     padded up to a dp-divisible count; the free list never hands out the
