@@ -259,9 +259,9 @@ def run_telemetry_overhead(params, cfg, *, n_slots: int, prompt_len: int,
                            repeats: int = 4) -> dict:
     """Decode throughput with the telemetry subsystem attached vs a plain
     engine on an identical saturating device-loop workload. The telemetry
-    engine carries the full instrumentation: registry histograms, trace
-    spans, and the per-tick int32 telemetry block riding the windowed
-    scan. Token streams are bit-identical either way (pinned by
+    engine carries the full instrumentation: registry histograms and the
+    recorded phase spans; it runs the plain engine's compiled programs.
+    Token streams are bit-identical either way (pinned by
     tests/test_telemetry.py); this measures only the overhead, and
     ``tools/check_bench.py`` gates ``ratio >= TELEMETRY_FLOOR`` (0.95).
 

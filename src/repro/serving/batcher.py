@@ -86,7 +86,7 @@ from repro.models import sharding
 from repro.models import transformer as T
 from repro.serving.controller import ModeController
 from repro.serving.session import Request, RequestQueue, Session
-from repro.serving.telemetry import Telemetry, now as _now
+from repro.serving.telemetry import Telemetry, now as _now, span
 
 
 def _slot_axis(cfg: ModelConfig) -> int:
@@ -197,7 +197,7 @@ class _EngineSteps:
 
 
 def _window_scan_body(cfg: ModelConfig, mesh, *, mixed: bool,
-                      fused_tail: bool, telemetry: bool = False):
+                      fused_tail: bool):
     """The ONE place the device-resident decode window's scan body is
     defined — shared by the dense and paged step builders (``bt=None``
     selects dense) and by the plain and mixed variants.
@@ -211,17 +211,8 @@ def _window_scan_body(cfg: ModelConfig, mesh, *, mixed: bool,
     into the next tick's embed — no separate head/argmax/feedback HLOs and
     no [B, V] f32 logits in HBM. ``fused_tail=False`` keeps the legacy
     logits+argmax body: the equivalence oracle ``tests/test_device_loop.py``
-    pins token streams against.
-
-    ``telemetry``: the body additionally emits a per-tick int32 telemetry
-    row ``[wire_bytes, live_slots, mode_hist[0..M-1]]`` computed from the
-    window's frozen live mask (``active``) and the per-mode payload table
-    (``pb_table``) — stacked to a ``[K, 2 + M]`` block that rides the scan
-    OUTPUT (result index 4) and is folded into the metrics registry one
-    window late, exactly like token values. Pure integer arithmetic on
-    inputs the untraced body already has: token bits are untouched."""
-    def run(params, stacked, tok, states, positions, modes_k, bt,
-            pb_table=None, active=None):
+    pins token streams against."""
+    def run(params, stacked, tok, states, positions, modes_k, bt):
         def body(carry, modes):
             tok, states, positions = carry
             if mixed:
@@ -234,55 +225,33 @@ def _window_scan_body(cfg: ModelConfig, mesh, *, mixed: bool,
                     return_tokens=fused_tail)
             nxt = out if fused_tail else jnp.argmax(out, axis=-1)
             nxt = nxt.astype(jnp.int32).reshape(tok.shape)
-            if telemetry:
-                row = jnp.concatenate([
-                    jnp.sum(active * pb_table[modes])[None],
-                    jnp.sum(active)[None],
-                    jnp.zeros(pb_table.shape[0], jnp.int32)
-                       .at[modes].add(active),
-                ]).astype(jnp.int32)
-                return (nxt, new_states, positions + 1), (nxt, row)
             return (nxt, new_states, positions + 1), nxt
 
         carry, out = jax.lax.scan(body, (tok, states, positions), modes_k)
-        if telemetry:
-            toks, tel = out
-            return (*carry, toks, tel)
         return (*carry, out)
 
     return run
 
 
 def _paged_steps(cfg: ModelConfig, mixed: bool, mesh=None,
-                 fused_tail: bool = True,
-                 telemetry: bool = False) -> _EngineSteps:
+                 fused_tail: bool = True) -> _EngineSteps:
     """Paged variants of the engine closures: every decode step threads the
     ``[B, nb]`` block table through to the paged attention path, and
     prefill writes straight into the (donated) page arena through the
     group's block tables instead of materializing dense per-row caches.
     The closures are shape-polymorphic in the table width (pow2-bucketed by
     the pool), so one set serves every arena size. ``mesh`` builds the
-    sharded variants (see :func:`_compiled_steps`); ``telemetry`` the
-    instrumented window bodies (two trailing ``pb_table``/``active``
-    args ahead of ``bt``)."""
+    sharded variants (see :func:`_compiled_steps`)."""
     run_mono = _window_scan_body(cfg, mesh, mixed=False,
-                                 fused_tail=fused_tail, telemetry=telemetry)
+                                 fused_tail=fused_tail)
 
     @jax.jit
     def mono_step(params, tok, states, pos, bt):
         return T.decode_step(params, tok, states, pos, cfg, block_table=bt)
 
-    if telemetry:
-        @functools.partial(jax.jit, donate_argnums=(2, 3))
-        def mono_step_dev(params, tok, states, positions, modes_k,
-                          pb_table, active, bt):
-            return run_mono(params, None, tok, states, positions, modes_k,
-                            bt, pb_table, active)
-    else:
-        @functools.partial(jax.jit, donate_argnums=(2, 3))
-        def mono_step_dev(params, tok, states, positions, modes_k, bt):
-            return run_mono(params, None, tok, states, positions, modes_k,
-                            bt)
+    @functools.partial(jax.jit, donate_argnums=(2, 3))
+    def mono_step_dev(params, tok, states, positions, modes_k, bt):
+        return run_mono(params, None, tok, states, positions, modes_k, bt)
 
     @functools.partial(jax.jit, donate_argnums=(3,))
     def mono_prefill(params, toks, lengths, arena, bt):
@@ -294,7 +263,7 @@ def _paged_steps(cfg: ModelConfig, mixed: bool, mesh=None,
         return _EngineSteps(mono_step, mono_step_dev, mono_prefill)
 
     run_mixed = _window_scan_body(cfg, mesh, mixed=True,
-                                  fused_tail=fused_tail, telemetry=telemetry)
+                                  fused_tail=fused_tail)
 
     @jax.jit
     def mixed_step(params, stacked, tok, states, positions, modes, bt):
@@ -302,18 +271,11 @@ def _paged_steps(cfg: ModelConfig, mixed: bool, mesh=None,
                                           positions, cfg, modes,
                                           block_table=bt, mesh=mesh)
 
-    if telemetry:
-        @functools.partial(jax.jit, donate_argnums=(3, 4))
-        def mixed_step_dev(params, stacked, tok, states, positions,
-                           modes_k, pb_table, active, bt):
-            return run_mixed(params, stacked, tok, states, positions,
-                             modes_k, bt, pb_table, active)
-    else:
-        @functools.partial(jax.jit, donate_argnums=(3, 4))
-        def mixed_step_dev(params, stacked, tok, states, positions,
-                           modes_k, bt):
-            return run_mixed(params, stacked, tok, states, positions,
-                             modes_k, bt)
+    @functools.partial(jax.jit, donate_argnums=(3, 4))
+    def mixed_step_dev(params, stacked, tok, states, positions, modes_k,
+                       bt):
+        return run_mixed(params, stacked, tok, states, positions, modes_k,
+                         bt)
 
     @functools.partial(jax.jit, donate_argnums=(4,))
     def mixed_prefill(params, stacked, toks, lengths, arena, modes, bt):
@@ -329,8 +291,7 @@ def _paged_steps(cfg: ModelConfig, mixed: bool, mesh=None,
 @functools.lru_cache(maxsize=None)
 def _compiled_steps(cfg: ModelConfig, cache_len: int, mixed: bool,
                     paged: bool = False, mesh=None,
-                    fused_tail: bool = True,
-                    telemetry: bool = False) -> _EngineSteps:
+                    fused_tail: bool = True) -> _EngineSteps:
     """Build (once per ``(cfg, cache_len)``) the jitted decode/prefill
     closures every ``ContinuousBatchingEngine`` runs on. Cached at module
     level so N engines of the same configuration — a cluster's replicas,
@@ -352,17 +313,12 @@ def _compiled_steps(cfg: ModelConfig, cache_len: int, mixed: bool,
 
     ``fused_tail`` (part of the cache key) selects the fused decode-tail
     window body — see :func:`_window_scan_body`; ``False`` builds the
-    legacy logits+argmax loop the device-loop equivalence tests run.
-
-    ``telemetry`` (part of the cache key — instrumented and plain engines
-    must not share traced functions) builds the window bodies that emit
-    the per-tick int32 telemetry block; the dev steps then take two extra
-    args (``pb_table [M]``, ``active [B]``) after the mode matrix."""
+    legacy logits+argmax loop the device-loop equivalence tests run."""
     if paged:
-        return _paged_steps(cfg, mixed, mesh, fused_tail, telemetry)
+        return _paged_steps(cfg, mixed, mesh, fused_tail)
 
     run_mono = _window_scan_body(cfg, mesh, mixed=False,
-                                 fused_tail=fused_tail, telemetry=telemetry)
+                                 fused_tail=fused_tail)
 
     @jax.jit
     def mono_step(params, tok, states, pos):
@@ -377,17 +333,9 @@ def _compiled_steps(cfg: ModelConfig, cache_len: int, mixed: bool,
     # on token values), so the host precomputes the window and reads
     # the [K, B] token block back one window late. Free slots ride
     # along (their positions drift, but admission rewrites them).
-    if telemetry:
-        @functools.partial(jax.jit, donate_argnums=(2, 3))
-        def mono_step_dev(params, tok, states, positions, modes_k,
-                          pb_table, active):
-            return run_mono(params, None, tok, states, positions, modes_k,
-                            None, pb_table, active)
-    else:
-        @functools.partial(jax.jit, donate_argnums=(2, 3))
-        def mono_step_dev(params, tok, states, positions, modes_k):
-            return run_mono(params, None, tok, states, positions, modes_k,
-                            None)
+    @functools.partial(jax.jit, donate_argnums=(2, 3))
+    def mono_step_dev(params, tok, states, positions, modes_k):
+        return run_mono(params, None, tok, states, positions, modes_k, None)
 
     @jax.jit
     def mono_prefill(params, toks, lengths):
@@ -410,20 +358,12 @@ def _compiled_steps(cfg: ModelConfig, cache_len: int, mixed: bool,
                                           mesh=mesh)
 
     run_mixed = _window_scan_body(cfg, mesh, mixed=True,
-                                  fused_tail=fused_tail, telemetry=telemetry)
+                                  fused_tail=fused_tail)
 
-    if telemetry:
-        @functools.partial(jax.jit, donate_argnums=(3, 4))
-        def mixed_step_dev(params, stacked, tok, states, positions,
-                           modes_k, pb_table, active):
-            return run_mixed(params, stacked, tok, states, positions,
-                             modes_k, None, pb_table, active)
-    else:
-        @functools.partial(jax.jit, donate_argnums=(3, 4))
-        def mixed_step_dev(params, stacked, tok, states, positions,
-                           modes_k):
-            return run_mixed(params, stacked, tok, states, positions,
-                             modes_k, None)
+    @functools.partial(jax.jit, donate_argnums=(3, 4))
+    def mixed_step_dev(params, stacked, tok, states, positions, modes_k):
+        return run_mixed(params, stacked, tok, states, positions, modes_k,
+                         None)
 
     @jax.jit
     def mixed_prefill(params, stacked, toks, lengths, modes):
@@ -834,14 +774,13 @@ class ContinuousBatchingEngine:
         # kernel (see _window_scan_body); False keeps the legacy
         # logits+argmax window — the token-identity oracle in tests
         self.fused_tail = bool(fused_tail)
-        # telemetry is OPTIONAL and additive: None (the default) compiles
-        # and runs the exact pre-telemetry engine; a Telemetry object
-        # selects the instrumented window bodies (a separate compile-cache
-        # entry) and turns on the guarded host-side observations below
+        # telemetry is OPTIONAL and host-only: a Telemetry object records
+        # the phase spans and the guarded host-side observations below;
+        # the engine runs the same compiled programs with or without it
         self._tel = telemetry
         steps = _compiled_steps(cfg, cache_len,
                                 self.stacked_bank is not None, self.paged,
-                                mesh, self.fused_tail, self._tel is not None)
+                                mesh, self.fused_tail)
         self.host_loop = host_loop
         self.max_window = max(int(max_window), 1)
         if not host_loop:
@@ -893,26 +832,13 @@ class ContinuousBatchingEngine:
         self._mixed_step_dev = steps.mixed_step_dev
         self._mixed_prefill = steps.mixed_prefill
 
-        #: host-side fold of the device telemetry blocks (wire bytes,
-        #: decoded slot-ticks, per-mode tick histogram) — the oracle the
-        #: telemetry tests cross-check against host wire accounting
-        self.device_tel = {"wire_bytes": 0, "slot_ticks": 0,
-                           "mode_ticks": np.zeros(0, np.int64)}
-        self._pb_table = None
-        if self._tel is not None:
-            n_modes = (cfg.split.n_modes
-                       if self.stacked_bank is not None else 1)
-            self.device_tel["mode_ticks"] = np.zeros(n_modes, np.int64)
-            self._pb_table = sharding.replicate(
-                jnp.asarray([self._payload_bytes(m)
-                             for m in range(n_modes)], jnp.int32), mesh)
-            if self.controller is not None:
-                tel = self._tel
-                self.controller.on_escalate = (
-                    lambda rid, tick, frm, to: (
-                        tel.inc("engine.mode_escalations"),
-                        tel.instant("mode_escalate", rid=rid, tick=tick,
-                                    cat="mode", frm=frm, to=to)))
+        if self._tel is not None and self.controller is not None:
+            tel = self._tel
+            self.controller.on_escalate = (
+                lambda rid, tick, frm, to: (
+                    tel.inc("engine.mode_escalations"),
+                    tel.instant("mode_escalate", rid=rid, tick=tick,
+                                cat="mode", frm=frm, to=to)))
 
     # -- submission -----------------------------------------------------------
     def submit(self, req: Request) -> bool:
@@ -942,16 +868,18 @@ class ContinuousBatchingEngine:
         Loops because a budget-1 session completes inside its own prefill
         (the prefill argmax is its whole generation) and frees its slot for
         the next queued request within the same tick."""
-        while self.pool.n_free and len(self.queue):
-            if not self.host_loop:
-                # admission scatters into the resident pool buffers — the
-                # pipeline must land the in-flight step first
-                self._sync_device_state()
-            admits = self._collect_admits()
-            if not admits:            # everything popped was over capacity
-                break
-            for blen, group in sorted(_group_by_bucket(admits).items()):
-                self._prefill_group(blen, group)
+        with span("engine.admit", self._tel):
+            while self.pool.n_free and len(self.queue):
+                if not self.host_loop:
+                    # admission scatters into the resident pool buffers —
+                    # the pipeline must land the in-flight step first
+                    self._sync_device_state()
+                with span("engine.collect_admits", self._tel):
+                    admits = self._collect_admits()
+                if not admits:        # everything popped was over capacity
+                    break
+                for blen, group in sorted(_group_by_bucket(admits).items()):
+                    self._prefill_group(blen, group)
 
     def _collect_admits(self) -> List[tuple]:
         admits: List[tuple] = []      # (req, slot, mode, budget, capacity)
@@ -1020,12 +948,13 @@ class ContinuousBatchingEngine:
             admits.append((req, slot, mode, budget, cap))
         return admits
 
-    def _prefill_group(self, blen: int, group: List[tuple]):
-        """ONE jitted full-sequence prefill for every request in a bucket:
-        prompts right-padded to ``blen``, batch padded to a power of two,
-        each row's boundary routed through its admission-chosen mode."""
+    def _launch_prefill(self, blen: int, group: List[tuple]):
+        """Dispatch the bucket's jitted prefill: prompts right-padded to
+        ``blen``, batch padded to a power of two, each row's boundary
+        routed through its admission-chosen mode. Returns the device's
+        first tokens and the prefilled states (paged: the updated arena,
+        already installed)."""
         n = len(group)
-        t_pre = _now() if self._tel is not None else 0.0
         bp = _bucket_len(n, lo=1)          # pow2 batch: bounded compile set
         audio = (self.cfg.frontend == "audio" and self.cfg.n_codebooks > 1)
         shape = (bp, self.cfg.n_codebooks, blen) if audio else (bp, blen)
@@ -1066,15 +995,21 @@ class ContinuousBatchingEngine:
         self.prefill_calls += 1
         self.prefill_tokens += int(lens[:n].sum())
         self.prefill_padded_tokens += bp * blen
+        return first_dev, new_states
+
+    def _prefill_group(self, blen: int, group: List[tuple]):
+        """ONE jitted full-sequence prefill for every request in a bucket
+        (:meth:`_launch_prefill`), then the admitted rows' first tokens,
+        positions and sessions."""
+        with span("engine.prefill", self._tel, "engine.prefill_s",
+                  rids=[a[0].rid for a in group], bucket=blen):
+            first_dev, new_states = self._launch_prefill(blen, group)
         # admission-time sync: the argmax already ran inside the jit, so
         # this materializes a tiny int32 array (once per admitted bucket,
         # not once per decode tick)
-        first = np.asarray(first_dev, np.int32)
+        with span("engine.prefill_wait", self._tel):
+            first = np.asarray(first_dev, np.int32)
         now = _now()
-        if self._tel is not None:
-            self._tel.complete("prefill", t_pre, now - t_pre, cat="window",
-                               rows=n, bucket=blen)
-            self._tel.observe("engine.prefill_s", now - t_pre)
         slots = [a[1] for a in group]
         plens = [a[0].prompt_len for a in group]
         if self.paged:
@@ -1375,45 +1310,38 @@ class ContinuousBatchingEngine:
                 return True
             return False
 
-        t0 = _now() if self._tel is not None else 0.0
-        k = self._window_len()
-        bt = None
-        if self.paged:
-            # the host precomputes the window's page appends exactly like
-            # the [K, B] mode matrix: every row the window will write
-            # (positions pos..pos+k-1 per live slot) gets its page BEFORE
-            # dispatch, and the block table ships as a fresh device copy
-            for slot in self.active:
-                self.pool.alloc_pages(slot,
-                                      int(self.pool.positions[slot]) + k)
-            bt = self.pool.block_table()
+        with span("engine.plan", self._tel):
+            k = self._window_len()
+            bt = None
+            if self.paged:
+                # the host precomputes the window's page appends exactly
+                # like the [K, B] mode matrix: every row the window will
+                # write (positions pos..pos+k-1 per live slot) gets its
+                # page BEFORE dispatch, and the block table ships as a
+                # fresh device copy
+                for slot in self.active:
+                    self.pool.alloc_pages(slot,
+                                          int(self.pool.positions[slot]) + k)
+                bt = self.pool.block_table()
         # the live-session set is frozen for the whole window (retirement
         # is budget-driven and happens after dispatch), so sort once and
         # reuse the ordering for every tick's mode selection AND as the
         # materialization snapshot
         snapshot = sorted(self.active.items())
-        modes_k = np.stack([self._choose_modes(self.tick + i,
-                                               items=snapshot)
-                            for i in range(k)])
+        with span("engine.choose_modes", self._tel, k=k,
+                  live=len(snapshot)):
+            modes_k = np.stack([self._choose_modes(self.tick + i,
+                                                   items=snapshot)
+                                for i in range(k)])
         prev = self._inflight
-        active = None
-        if self._tel is not None:
-            # the live set is frozen per window — the int32 mask both
-            # masks free slots out of the device telemetry block and lets
-            # its wire sum match host accounting exactly
-            active = np.zeros(self.pool.n_slots, np.int32)
-            for slot, _ in snapshot:
-                active[slot] = 1
-        fut = self._dispatch_device_step(modes_k, bt, active)
+        with span("engine.dispatch", self._tel, "engine.window_dispatch_s",
+                  k=k, live=len(snapshot), tick=self.tick):
+            fut = self._dispatch_device_step(modes_k, bt)
         # snapshot BEFORE retirement: these sessions each emit one token
         # per window tick, whose values land at the next materialization
         self._inflight = (snapshot, fut, k, _now() if self._tel is not None
                           else 0.0)
         if self._tel is not None:
-            self._tel.complete("window_dispatch", t0, _now() - t0,
-                               cat="window", k=k, live=len(snapshot),
-                               tick=self.tick)
-            self._tel.observe("engine.window_dispatch_s", _now() - t0)
             self._tel.set("engine.queue_depth", len(self.queue))
             self._tel.set("engine.slot_occupancy",
                           len(snapshot) / self.pool.n_slots)
@@ -1421,47 +1349,53 @@ class ContinuousBatchingEngine:
                 self._tel.set("engine.page_occupancy",
                               self.pool.pages_in_use
                               / max(self.pool.n_pages, 1))
+            # the host's own accounting of the window (_choose_modes
+            # charged each session these bytes), as the host loop counts
+            self._tel.inc("engine.decode_wire_bytes",
+                          sum(self._payload_bytes(int(m))
+                              for slot, _ in snapshot
+                              for m in modes_k[:, slot]))
+            self._tel.inc("engine.decode_tokens", k * len(snapshot))
 
-        self.decode_ticks += k
-        self.decoded_slot_ticks += k * len(snapshot)
-        active_slots = set(self.active)
-        for i in range(k):
-            if len({int(m) for s, m in enumerate(modes_k[i])
-                    if s in active_slots}) > 1:
-                self.mode_mix_ticks += 1
+        with span("engine.retire", self._tel):
+            self.decode_ticks += k
+            self.decoded_slot_ticks += k * len(snapshot)
+            active_slots = set(self.active)
+            for i in range(k):
+                if len({int(m) for s, m in enumerate(modes_k[i])
+                        if s in active_slots}) > 1:
+                    self.mode_mix_ticks += 1
 
-        # budget-based retirement at dispatch time: frees slots for the
-        # next tick's admission without waiting for token values (sessions
-        # can only complete at the window's last tick — _window_len never
-        # overshoots the earliest completion)
-        for slot, sess in snapshot:
-            sess.pos += k
-            self.pool.positions[slot] += k
-            emitted = sess.pos - sess.request.prompt_len + 1  # incl. prefill
-            budget = sess.gen_budget or sess.request.max_new_tokens
-            if emitted >= budget:
-                sess.finished_tick = self.tick + k - 1
-                self._release_links(sess)
-                del self.active[slot]
-                self.pool.release(slot)
+            # budget-based retirement at dispatch time: frees slots for
+            # the next tick's admission without waiting for token values
+            # (sessions can only complete at the window's last tick —
+            # _window_len never overshoots the earliest completion)
+            for slot, sess in snapshot:
+                sess.pos += k
+                self.pool.positions[slot] += k
+                emitted = sess.pos - sess.request.prompt_len + 1  # +prefill
+                budget = sess.gen_budget or sess.request.max_new_tokens
+                if emitted >= budget:
+                    sess.finished_tick = self.tick + k - 1
+                    self._release_links(sess)
+                    del self.active[slot]
+                    self.pool.release(slot)
         # sync the PREVIOUS window's tokens while the device runs this one
         if prev is not None:
             self._materialize(prev)
         self.tick += k
         return True
 
-    def _dispatch_device_step(self, modes_k: np.ndarray, bt=None,
-                              active: Optional[np.ndarray] = None) \
-            -> _cf.Future:
+    def _dispatch_device_step(self, modes_k: np.ndarray,
+                              bt=None) -> _cf.Future:
         """Enqueue one fused decode window on the pipeline worker. The
         closure chains on the previous window's future (single worker =
         FIFO, so ``prev.result()`` never blocks the worker on unfinished
         work); the main thread returns immediately and keeps doing host
         bookkeeping while XLA executes. ``bt`` (paged pools) is the
         window's frozen block table — a fresh device buffer, never
-        donated. ``active`` (telemetry engines) is the window's frozen
-        int32 live mask feeding the instrumented bodies' telemetry
-        block."""
+        donated. The worker's ``engine.launch`` span covers the wait for
+        the previous window's call and this window's dispatch."""
         prev, cur = self._future, (self.cur_tokens, self.pool.states,
                                    self._positions)
         # [K, B]: the slot axis is axis 1 inside the window scan
@@ -1469,26 +1403,17 @@ class ContinuousBatchingEngine:
                                          axis=1)
         params, stacked = self.params, self.stacked_bank
         mixed, mono = self._mixed_step_dev, self._mono_step_dev
-        tel_args = ()
-        if self._tel is not None:
-            tel_args = (self._pb_table,
-                        sharding.shard_batch(jnp.asarray(active),
-                                             self.mesh))
+        tail = () if bt is None else (bt,)
 
         def work():
-            tok, states, positions = prev.result()[:3] if prev is not None \
-                else cur
-            if mixed is not None:
-                if bt is not None:
+            with span("engine.launch"):
+                tok, states, positions = prev.result()[:3] \
+                    if prev is not None else cur
+                if mixed is not None:
                     return mixed(params, stacked, tok, states, positions,
-                                 modes_dev, *tel_args, bt)
-                return mixed(params, stacked, tok, states, positions,
-                             modes_dev, *tel_args)
-            if bt is not None:
+                                 modes_dev, *tail)
                 return mono(params, tok, states, positions, modes_dev,
-                            *tel_args, bt)
-            return mono(params, tok, states, positions, modes_dev,
-                        *tel_args)
+                            *tail)
 
         fut = self._pipeline().submit(work)
         self._future = fut
@@ -1530,50 +1455,35 @@ class ContinuousBatchingEngine:
         run — because while a window is in flight those attributes point at
         stale (donated) buffers."""
         if self._future is not None:
-            self.cur_tokens, self.pool.states, self._positions = \
-                self._future.result()[:3]
+            with span("engine.sync_wait", self._tel):
+                self.cur_tokens, self.pool.states, self._positions = \
+                    self._future.result()[:3]
             self._future = None
 
     def _materialize(self, inflight):
         """Host side of the lagged pipeline: copy one window's [K, B]
         int32 token block off the device and append it to the snapshot's
         sessions; sessions whose budget completed in that window move to
-        ``finished`` here (their slots were already freed at dispatch).
-        On telemetry engines the window's [K, 2 + M] int32 telemetry
-        block rides the same result and folds into the registry here —
-        one window late, exactly like token values."""
+        ``finished`` here (their slots were already freed at dispatch)."""
         snapshot, fut, k, t_disp = inflight
-        t_mat = _now() if self._tel is not None else 0.0
-        arr = np.asarray(fut.result()[3])            # [K, B, ...]
-        if self._tel is not None:
-            tel_blk = np.asarray(fut.result()[4], np.int64)  # [K, 2 + M]
-            wire = int(tel_blk[:, 0].sum())
-            slot_ticks = int(tel_blk[:, 1].sum())
-            self.device_tel["wire_bytes"] += wire
-            self.device_tel["slot_ticks"] += slot_ticks
-            self.device_tel["mode_ticks"] += tel_blk[:, 2:].sum(axis=0)
-            self._tel.inc("engine.decode_wire_bytes", wire)
-            self._tel.inc("engine.decode_tokens", slot_ticks)
-            # window wall clock (dispatch -> tokens on host) over k ticks
-            # IS the device loop's inter-token latency, weighted by the
-            # tokens the window produced
-            wall = _now() - t_disp
-            if slot_ticks:
-                self._tel.observe("engine.intertoken_s", wall / k,
-                                  slot_ticks)
-        for slot, sess in snapshot:
-            for i in range(k):
-                tok = arr[i, slot]
-                sess.tokens.append(int(tok.reshape(-1)[0]) if tok.ndim
-                                   else int(tok))
-            budget = sess.gen_budget or sess.request.max_new_tokens
-            if len(sess.tokens) >= budget:
-                self.finished.append(sess)
-        if self._tel is not None:
-            dur = _now() - t_mat
-            self._tel.complete("window_materialize", t_mat, dur,
-                               cat="window", k=k)
-            self._tel.observe("engine.window_materialize_s", dur)
+        with span("engine.materialize", self._tel,
+                  "engine.window_materialize_s", k=k):
+            with span("engine.materialize_wait", self._tel):
+                arr = np.asarray(fut.result()[3])    # [K, B, ...]
+            if self._tel is not None:
+                # window wall clock (dispatch -> tokens on host) over k
+                # ticks IS the device loop's inter-token latency, weighted
+                # by the tokens the window produced
+                self._tel.observe("engine.intertoken_s",
+                                  (_now() - t_disp) / k, k * len(snapshot))
+            for slot, sess in snapshot:
+                for i in range(k):
+                    tok = arr[i, slot]
+                    sess.tokens.append(int(tok.reshape(-1)[0]) if tok.ndim
+                                       else int(tok))
+                budget = sess.gen_budget or sess.request.max_new_tokens
+                if len(sess.tokens) >= budget:
+                    self.finished.append(sess)
 
     def _materialize_inflight(self):
         if self._inflight is not None:
@@ -1623,9 +1533,6 @@ class ContinuousBatchingEngine:
         if self.paged:
             self.pool.peak_pages_in_use = self.pool.pages_in_use
         self.queue.submitted = self.queue.rejected = 0
-        self.device_tel["wire_bytes"] = self.device_tel["slot_ticks"] = 0
-        self.device_tel["mode_ticks"] = np.zeros_like(
-            self.device_tel["mode_ticks"])
         if self._tel is not None:
             # shared across a cluster's replicas — a reset between warm-up
             # and measurement clears everyone's warm data, which is what

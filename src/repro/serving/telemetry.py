@@ -1,24 +1,28 @@
-"""Serving telemetry: metrics registry + structured trace timeline.
-
-Two halves, both dependency-free (numpy only):
+"""Serving telemetry: metrics registry, structured trace timeline, and the
+engine's phase spans.
 
 1. :class:`MetricsRegistry` — named counters, gauges, and log-bucketed
    histograms (geometric bucket edges, ``np.searchsorted`` placement)
    with p50/p90/p99/max summaries, exportable as a JSON snapshot
    (:meth:`MetricsRegistry.snapshot`) or Prometheus text exposition
    (:meth:`MetricsRegistry.prometheus`). The serving engines observe
-   TTFT, inter-token latency, window dispatch/materialize wall time,
+   TTFT, inter-token latency, prefill/dispatch/materialize wall time,
    wire bytes, queue depth, and pool occupancy into it; the existing
    ``stats()`` dicts are mirrored in via :meth:`MetricsRegistry.ingest`
    so both views always agree.
 
 2. :class:`TraceRecorder` — a bounded ring buffer of structured events
    (admission verdicts, mode switches and escalations, migration
-   send/inject, handovers, autoscale decisions, decode-window spans)
+   send/inject, handovers, autoscale decisions, engine phase spans)
    stamped on the shared monotonic clock and exportable as Chrome
    trace-event JSON (:meth:`TraceRecorder.chrome_trace`), loadable in
    Perfetto / ``chrome://tracing``. Lanes (one per cluster replica,
    plus a control-plane lane) render as separate processes.
+
+3. :func:`span` — THE span mechanism: every engine phase span enters a
+   ``jax.profiler.TraceAnnotation`` of its name (so a profiler trace
+   lines it up with device time), and, only when a :class:`Telemetry` is
+   attached, is also recorded into its timeline and histogram.
 
 :class:`Telemetry` bundles one registry + one recorder + a lane id; an
 ``EdgeCluster`` hands each replica a :meth:`Telemetry.for_lane` view so
@@ -30,11 +34,9 @@ and the training loop all read it) and the shared bench timing helpers
 (:class:`Stopwatch`, :func:`best_of`, :func:`time_us`) that the
 benchmarks previously each re-implemented.
 
-The device-resident decode loop never calls into this module from
-traced code: per-tick occupancy/mode/wire counters ride the windowed
-``lax.scan`` as an int32 telemetry block (see
-``batcher._window_scan_body``) and are folded into the registry one
-window late, on the host, exactly like token values.
+Nothing here runs inside traced code: attaching a ``Telemetry`` to an
+engine compiles nothing the plain engine does not; every counter it
+feeds comes from the host's own accounting.
 """
 from __future__ import annotations
 
@@ -45,7 +47,9 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 # ---------------------------------------------------------------------------
@@ -375,15 +379,6 @@ class TraceRecorder:
                     "ts": self._us(t_start), "dur": dur_s * 1e6,
                     "pid": int(lane), "tid": 0, "args": args})
 
-    @contextlib.contextmanager
-    def span(self, name: str, *, lane: int = 0, cat: str = "serving",
-             **args):
-        t0 = now()
-        try:
-            yield
-        finally:
-            self.complete(name, t0, now() - t0, lane=lane, cat=cat, **args)
-
     def events(self) -> list:
         return list(self._events)
 
@@ -436,9 +431,6 @@ class Telemetry:
     def instant(self, name: str, **args):
         self.trace.instant(name, lane=self.lane, **args)
 
-    def span(self, name: str, **args):
-        return self.trace.span(name, lane=self.lane, **args)
-
     def complete(self, name: str, t_start: float, dur_s: float, **args):
         self.trace.complete(name, t_start, dur_s, lane=self.lane, **args)
 
@@ -450,6 +442,48 @@ class Telemetry:
 
     def observe(self, name: str, v, n: int = 1):
         self.registry.observe(name, v, n)
+
+
+# ---------------------------------------------------------------------------
+# phase spans
+# ---------------------------------------------------------------------------
+
+class _Recorded:
+    """A profiler span that is also recorded into a ``Telemetry``."""
+
+    __slots__ = ("name", "tel", "hist", "args", "ann", "t0")
+
+    def __init__(self, name, tel, hist, args):
+        self.name, self.tel, self.hist, self.args = name, tel, hist, args
+        self.ann = TraceAnnotation(name)
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t0 = now()
+        return self
+
+    def __exit__(self, *exc):
+        dur = now() - self.t0
+        self.tel.complete(self.name, self.t0, dur, **self.args)
+        if self.hist is not None:
+            self.tel.observe(self.hist, dur)
+        return self.ann.__exit__(*exc)
+
+
+def span(name: str, tel: Optional[Telemetry] = None,
+         hist: Optional[str] = None, **args):
+    """A phase span: ``with span("engine.plan", tel): ...``.
+
+    Always a ``jax.profiler.TraceAnnotation(name)`` (no arguments, so the
+    profiler trace names it exactly ``name``; inert and about a
+    microsecond when no profiler runs). With ``tel`` it is also recorded
+    into ``tel``'s timeline with ``args`` and, given ``hist``, observed
+    into that histogram in seconds. A name ending in ``_wait`` means the
+    host is blocked on something else (the device or the pipeline
+    worker); no other span means that."""
+    if tel is None:
+        return TraceAnnotation(name)
+    return _Recorded(name, tel, hist, args)
 
 
 # ---------------------------------------------------------------------------
@@ -465,7 +499,6 @@ def profile_capture(profile_dir: Optional[str]):
     if not profile_dir:
         yield
         return
-    import jax
     jax.profiler.start_trace(profile_dir)
     try:
         yield

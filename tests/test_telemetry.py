@@ -1,16 +1,19 @@
-"""Serving telemetry: registry oracles, trace round-trip, and the
-no-behavior-change contract.
+"""Serving telemetry: registry oracles, trace round-trip, the engine's
+phase spans, and the no-behavior-change contract.
 
 The telemetry subsystem (``repro.serving.telemetry``) must be purely
 additive: attaching a ``Telemetry`` to an engine may not change a single
 decoded token bit, on either the host loop or the device-resident
-windowed loop, for any decode-state family. These tests pin that, plus
-the registry's percentile math against a ``np.quantile`` oracle, the
-Chrome-trace JSON round-trip Perfetto relies on, the device telemetry
-block's wire accounting against the host's, and the cluster timeline's
-per-replica lanes with admission/migration/autoscale events.
+windowed loop, for any decode-state family, nor compile a program the
+plain engine lacks. These tests pin that, plus the registry's percentile
+math against a ``np.quantile`` oracle, the Chrome-trace JSON round-trip
+Perfetto relies on, the registry's wire counters against the host's
+accounting, the phase spans in a ``jax.profiler`` trace, and the cluster
+timeline's per-replica lanes with admission/migration/autoscale events.
 """
+import glob
 import json
+import os
 
 import jax
 import numpy as np
@@ -27,7 +30,7 @@ from repro.serving import (Autoscaler, AutoscalerConfig,
                            ContinuousBatchingEngine, EdgeCluster,
                            MetricsRegistry, Request, SLOAdmission,
                            SLOAdmissionConfig, Telemetry, TraceRecorder)
-from repro.serving.telemetry import Histogram
+from repro.serving.telemetry import Histogram, span as phase_span
 
 ARCHS = ["qwen2.5-3b", "recurrentgemma-2b", "xlstm-125m"]
 
@@ -95,12 +98,13 @@ def test_registry_snapshot_prometheus_and_reset():
 # ---------------------------------------------------------------------------
 
 def test_trace_chrome_json_round_trip(tmp_path):
-    tr = TraceRecorder(capacity=64)
-    tr.set_lane(0, "cluster")
-    tr.set_lane(1, "replica0")
+    tel = Telemetry(trace_capacity=64, lane=0, lane_name="cluster")
+    tr = tel.trace
     tr.instant("admit", lane=0, cat="admission", rid=1)
-    with tr.span("window", lane=1, cat="window", ticks=4):
+    with phase_span("window", tel.for_lane(1, "replica0"), "window_s",
+              cat="window", ticks=4):
         pass
+    assert tel.registry.snapshot()["window_s"]["count"] == 1
     path = tr.export(str(tmp_path / "trace.json"))
     doc = json.load(open(path))
     evs = doc["traceEvents"]
@@ -177,14 +181,13 @@ def test_telemetry_changes_no_token_bits(arch, host_loop):
     """The no-behavior-change contract: the instrumented engine decodes
     the exact streams the plain engine decodes — tokens, modes, wire,
     lifecycle ticks — on both the host loop and the device windowed
-    loop (where telemetry recompiles the scan with an extra int32
-    output)."""
+    loop (where the engine runs the plain engine's compiled programs)."""
     cfg = get_reduced(arch)
     params = SP.init_split_params(jax.random.PRNGKey(0), cfg)
     plain_done, plain_st, _, _ = _run(params, cfg, host_loop=host_loop,
                                       telemetry=False)
-    tel_done, tel_st, tel, eng = _run(params, cfg, host_loop=host_loop,
-                                      telemetry=True)
+    tel_done, tel_st, tel, _ = _run(params, cfg, host_loop=host_loop,
+                                    telemetry=True)
 
     plain = {s.request.rid: s for s in plain_done}
     instr = {s.request.rid: s for s in tel_done}
@@ -205,15 +208,16 @@ def test_telemetry_changes_no_token_bits(arch, host_loop):
     snap = tel.registry.snapshot()
     assert snap["engine.ttft_s"]["count"] == 10
     assert snap["engine.decode_wire_bytes"] == tel_st["decode_wire_bytes"]
-    if not host_loop:
-        # device telemetry block vs host accounting: the int32 row
-        # summed over the scan must reproduce the host's decode wire
-        # bytes and per-mode tick histogram exactly
-        assert eng.device_tel["wire_bytes"] == tel_st["decode_wire_bytes"]
-        assert eng.device_tel["slot_ticks"] == sum(
-            len(s.tokens) - 1 for s in tel_done)
-        assert int(eng.device_tel["mode_ticks"].sum()) \
-            == eng.device_tel["slot_ticks"]
+    # the registry's decode counters are the host's accounting: wire
+    # bytes charged per session, one token per live slot per tick, and
+    # one mode per decoded token
+    assert snap["engine.decode_wire_bytes"] == sum(
+        s.wire_bytes - s.prefill_wire_bytes for s in tel_done)
+    assert snap["engine.decode_tokens"] == sum(
+        len(s.tokens) - 1 for s in tel_done)
+    assert snap["engine.decode_tokens"] == tel_st["decoded_slot_ticks"]
+    assert sum(tel_st["mode_counts"].values()) \
+        == snap["engine.decode_tokens"]
 
 
 def test_reset_counters_clears_registry():
@@ -225,7 +229,113 @@ def test_reset_counters_clears_registry():
     eng.warm(np.array([1, 2, 3], np.int32))    # ends in reset_counters
     snap = tel.registry.snapshot()
     assert snap["engine.ttft_s"]["count"] == 0
-    assert eng.device_tel["wire_bytes"] == 0
+    assert snap["engine.decode_wire_bytes"] == 0
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "xlstm-125m"])
+def test_telemetry_compiles_nothing_new(arch):
+    """Attaching a Telemetry selects no other program: once a plain
+    engine has run a workload, an instrumented engine runs the same
+    workload without a single backend compile (paged and dense pools)."""
+    cfg = get_reduced(arch)
+    params = SP.init_split_params(jax.random.PRNGKey(0), cfg)
+    _run(params, cfg, host_loop=False, telemetry=False)
+    compiles = []
+
+    def listen(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        _run(params, cfg, host_loop=False, telemetry=True)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+
+
+# the serving thread's spans, each with the span it must lie inside
+# (None: outermost among the program's spans). The pipeline also lands
+# outside admission: when the pool drains, and at close().
+SERVING_SPANS = {
+    "engine.admit": None,
+    "engine.sync_wait": ("engine.admit", None),
+    "engine.collect_admits": "engine.admit",
+    "engine.prefill": "engine.admit",
+    "engine.prefill_wait": "engine.admit",
+    "engine.plan": None,
+    "engine.choose_modes": None,
+    "engine.dispatch": None,
+    "engine.retire": None,
+    "engine.materialize": None,
+    "engine.materialize_wait": "engine.materialize",
+}
+
+
+def test_phase_spans_in_the_profiler_trace(tmp_path):
+    """Every phase span reaches the ``jax.profiler`` trace under its own
+    name, nested as the engine nests the work: admission's parts inside
+    ``engine.admit``, the host's waits inside the phase that waits, the
+    window's phases outermost, and ``engine.launch`` alone on the
+    pipeline worker's thread. The attached Telemetry records the same
+    spans into its timeline and histograms."""
+    from jax.profiler import ProfileData
+    cfg = get_reduced("qwen2.5-3b")
+    params = SP.init_split_params(jax.random.PRNGKey(0), cfg)
+    tel = Telemetry()
+    eng = ContinuousBatchingEngine(params, cfg, n_slots=3, cache_len=32,
+                                   orchestrator=_orch(cfg), telemetry=tel)
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run(_requests(cfg, 10))
+        eng.close()
+    path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    host = [p for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:")]
+    lines = []
+    for p in host:
+        for line in p.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events if e.name.startswith("engine.")]
+            if evs:
+                lines.append(evs)
+    serving = [evs for evs in lines
+               if any(n != "engine.launch" for n, _, _ in evs)]
+    worker = [evs for evs in lines
+              if all(n == "engine.launch" for n, _, _ in evs)]
+    assert len(serving) == 1 and len(worker) == 1
+    serving, worker = serving[0], worker[0]
+    assert len(worker) >= 2                  # one launch per window
+    names = {n for n, _, _ in serving}
+    assert names == set(SERVING_SPANS)
+    for name, parent in SERVING_SPANS.items():
+        for _, s, e in (ev for ev in serving if ev[0] == name):
+            holders = [p for p, ps, pe in serving
+                       if (ps, pe) != (s, e) and ps <= s and e <= pe]
+            allowed = parent if isinstance(parent, tuple) else (parent,)
+            assert holders in [[] if p is None else [p] for p in allowed], \
+                (name, holders)
+    admit = [(s, e) for n, s, e in serving if n == "engine.admit"]
+    assert any(ps <= s and e <= pe for n, s, e in serving
+               if n == "engine.sync_wait" for ps, pe in admit)
+    # the prefill's dispatch ends before the host blocks on its tokens
+    pre = sorted((s, e) for n, s, e in serving if n == "engine.prefill")
+    wait = sorted((s, e) for n, s, e in serving if n == "engine.prefill_wait")
+    assert len(pre) == len(wait) and all(
+        p[1] <= w[0] for p, w in zip(pre, wait))
+
+    recorded = {e["name"] for e in tel.trace.events() if e["ph"] == "X"}
+    assert recorded == set(SERVING_SPANS)
+    prefill = next(e for e in tel.trace.events()
+                   if e["name"] == "engine.prefill")
+    assert prefill["args"]["rids"] and "bucket" in prefill["args"]
+    snap = tel.registry.snapshot()
+    for hist, name in (("engine.prefill_s", "engine.prefill"),
+                       ("engine.window_dispatch_s", "engine.dispatch"),
+                       ("engine.window_materialize_s",
+                        "engine.materialize")):
+        assert snap[hist]["count"] == sum(
+            1 for e in tel.trace.events() if e["name"] == name)
 
 
 # ---------------------------------------------------------------------------
