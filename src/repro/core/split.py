@@ -48,21 +48,6 @@ def _kinds(cfg: ModelConfig):
     return tuple(cfg.block_kind(i) for i in range(cfg.n_layers))
 
 
-def _split_states(states, cfg: ModelConfig, s: int):
-    """(encoder_states, decoder_states) views of the per-layer decode state."""
-    if cfg.homogeneous:
-        return (jax.tree.map(lambda a: a[:s], states),
-                jax.tree.map(lambda a: a[s:], states))
-    return states[:s], states[s:]
-
-
-def _merge_states(enc_new, dec_new, cfg: ModelConfig):
-    if cfg.homogeneous:
-        return jax.tree.map(
-            lambda a, b: jnp.concatenate([a, b], axis=0), enc_new, dec_new)
-    return tuple(enc_new) + tuple(dec_new)
-
-
 # ---------------------------------------------------------------------------
 # full-sequence split forward (training / prefill)
 # ---------------------------------------------------------------------------
@@ -139,11 +124,8 @@ def split_decode_step(params, token, states, cur_pos, cfg: ModelConfig,
     """
     s = cfg.split.split_at
     x = T.embed_tokens(params, token, cfg, None)
-    enc_l, dec_l = slice_layers(params["layers"], cfg, s)
-    enc_st, dec_st = _split_states(states, cfg, s)
-    kinds = _kinds(cfg)
-    x, enc_new = T.run_layers_decode(enc_l, x, enc_st, cur_pos, cfg,
-                                     kinds=kinds[:s])
+    x, states = T.run_layers_decode(params["layers"], x, states, cur_pos, cfg,
+                                    stop=s)
     B = x.shape[0]
     if mode == 0:
         payload = (x, None)
@@ -152,15 +134,14 @@ def split_decode_step(params, token, states, cur_pos, cfg: ModelConfig,
         payload = bottleneck.encode(params["bneck_modes"][mode - 1], x, bits)
         x = bottleneck.decode(params["bneck_modes"][mode - 1], *payload, bits,
                               dtype=T.model_dtype(cfg))
-    x, dec_new = T.run_layers_decode(dec_l, x, dec_st, cur_pos, cfg,
-                                     kinds=kinds[s:])
+    x, states = T.run_layers_decode(params["layers"], x, states, cur_pos, cfg,
+                                    start=s)
     pb = bottleneck.mode_payload_bytes(cfg, B, 1, mode)
     if return_tokens:
-        return (T.decode_tail_tokens(params, x, cfg),
-                _merge_states(enc_new, dec_new, cfg), pb)
+        return T.decode_tail_tokens(params, x, cfg), states, pb
     x = T.norm_apply_final(params, x, cfg)
     logits = T.lm_logits(params, x, cfg)
-    return logits, _merge_states(enc_new, dec_new, cfg), pb
+    return logits, states, pb
 
 
 def split_decode_step_mixed(params, stacked_bank, token, states, positions,
@@ -180,8 +161,9 @@ def split_decode_step_mixed(params, stacked_bank, token, states, positions,
     static mode table, not on traced values) — see
     ``bottleneck.mode_payload_bytes(cfg, 1, 1, mode)`` per slot.
     With ``block_table`` ([B, nb] int32, paged serving) the attention
-    leaves of ``states`` are page arenas shared by both halves — the layer
-    axis splits exactly like dense stacked leaves.
+    leaves of ``states`` are page arenas shared by both halves. Both halves
+    run over layer ranges of the whole stack (``0..split_at`` and
+    ``split_at..L``), so no half of the weights or state is copied.
 
     ``mesh``: serving ``('dp','mp')`` mesh for the sharded engine — the
     boundary runs in a replicated ``shard_map`` region (bit-identity with
@@ -195,22 +177,18 @@ def split_decode_step_mixed(params, stacked_bank, token, states, positions,
     """
     s = cfg.split.split_at
     x = T.embed_tokens(params, token, cfg, None)
-    enc_l, dec_l = slice_layers(params["layers"], cfg, s)
-    enc_st, dec_st = _split_states(states, cfg, s)
-    kinds = _kinds(cfg)
-    x, enc_new = T.run_layers_decode(enc_l, x, enc_st, positions, cfg,
-                                     kinds=kinds[:s], block_table=block_table)
+    x, states = T.run_layers_decode(params["layers"], x, states, positions,
+                                    cfg, block_table=block_table, stop=s)
     x = bottleneck.boundary_mixed(stacked_bank, x, mode_idx,
                                   dtype=T.model_dtype(cfg), mesh=mesh)
     x = sharding.constrain_batch(x, mesh)
-    x, dec_new = T.run_layers_decode(dec_l, x, dec_st, positions, cfg,
-                                     kinds=kinds[s:], block_table=block_table)
+    x, states = T.run_layers_decode(params["layers"], x, states, positions,
+                                    cfg, block_table=block_table, start=s)
     if return_tokens:
-        return T.decode_tail_tokens(params, x, cfg), _merge_states(
-            enc_new, dec_new, cfg)
+        return T.decode_tail_tokens(params, x, cfg), states
     x = T.norm_apply_final(params, x, cfg)
     logits = T.lm_logits(params, x, cfg)
-    return logits, _merge_states(enc_new, dec_new, cfg)
+    return logits, states
 
 
 # ---------------------------------------------------------------------------
@@ -228,21 +206,18 @@ def _prefill_through(params, tokens, cfg: ModelConfig, states, boundary,
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     if lengths is not None:
         lengths = jnp.asarray(lengths, jnp.int32)
-    enc_l, dec_l = slice_layers(params["layers"], cfg, s)
-    enc_st, dec_st = _split_states(states, cfg, s)
-    kinds = _kinds(cfg)
-    x, enc_new = T.run_layers_prefill(enc_l, x, positions, enc_st, cfg,
-                                      kinds=kinds[:s], lengths=lengths,
-                                      block_table=block_table)
+    x, states = T.run_layers_prefill(params["layers"], x, positions, states,
+                                     cfg, lengths=lengths,
+                                     block_table=block_table, stop=s)
     x = boundary(x)
-    x, dec_new = T.run_layers_prefill(dec_l, x, positions, dec_st, cfg,
-                                      kinds=kinds[s:], lengths=lengths,
-                                      block_table=block_table)
+    x, states = T.run_layers_prefill(params["layers"], x, positions, states,
+                                     cfg, lengths=lengths,
+                                     block_table=block_table, start=s)
     last = (lengths - 1 if lengths is not None
             else jnp.full((B,), S - 1, jnp.int32))
     x = jnp.take_along_axis(x, last[:, None, None], axis=1)
     x = T.norm_apply_final(params, x, cfg)
-    return T.lm_logits(params, x, cfg), _merge_states(enc_new, dec_new, cfg)
+    return T.lm_logits(params, x, cfg), states
 
 
 def split_prefill(params, tokens, cfg: ModelConfig, states, mode: int = 0, *,

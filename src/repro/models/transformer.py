@@ -5,9 +5,9 @@ Homogeneous attention stacks (dense, moe, vlm, audio) use stacked layer
 params + ``jax.lax.scan`` with per-layer remat; heterogeneous block patterns
 (recurrentgemma, xlstm) use an unrolled loop over per-layer param tuples.
 
-The split-learning machinery in ``repro.core.split`` slices the same layer
-params into encoder/decoder halves, so every forward path here is expressed
-through ``run_layers`` / ``run_layers_decode``.
+The split-learning machinery in ``repro.core.split`` runs the same layer
+params as encoder/decoder halves: training slices them, serving runs the
+decode/prefill helpers over a layer range of the whole stack.
 """
 from __future__ import annotations
 
@@ -390,50 +390,62 @@ def run_layers(layers, x, positions, cfg: ModelConfig, *, train: bool,
     return x, aux
 
 
-def run_layers_decode(layers, x, states, cur_pos, cfg: ModelConfig,
-                      kinds: Optional[Tuple[str, ...]] = None,
-                      block_table=None):
-    """One-token decode through a group of layers. Returns (x, new_states).
-    ``block_table`` (paged serving) is shared by every attention layer —
-    the scan closes over it while the per-layer arenas ride the carry."""
-    if cfg.homogeneous:
-        def body(h, inp):
-            lp, st = inp
-            h, new_st = block_apply_decode(lp, h, st, cur_pos, cfg, "attn",
-                                           block_table)
-            return h, new_st
-        x, new_states = jax.lax.scan(body, x, (layers, states))
-        return x, new_states
+def _run_layer_range(step, layers, x, states, cfg: ModelConfig, start: int,
+                     stop: Optional[int]):
+    """Run ``step(h, layer_params, layer_state, kind) -> (h, new_state)``
+    over layers ``start..stop`` (default: to the end) of the WHOLE stack and
+    return (x, states) with those layers' states replaced.
 
-    kinds = kinds or tuple(cfg.block_kind(i) for i in range(len(layers)))
-    new_states = []
-    for lp, st, kind in zip(layers, states, kinds):
-        x, ns = block_apply_decode(lp, x, st, cur_pos, cfg, kind, block_table)
-        new_states.append(ns)
+    Homogeneous stacks scan over the layer indices: each iteration indexes
+    layer ``i`` out of the full ``[L, ...]`` params the scan closes over and
+    the full state stack it carries, and writes the new state back in place.
+    Slicing the stacks at ``start``/``stop`` instead would make each half a
+    buffer of its own — a copy of half the weights on every call. Tuples of
+    per-layer pytrees (heterogeneous stacks) loop in Python, where a range
+    of a tuple copies nothing."""
+    stop = cfg.n_layers if stop is None else stop
+    if cfg.homogeneous:
+        def body(carry, i):
+            h, sts = carry
+            at = functools.partial(jax.lax.dynamic_index_in_dim, index=i,
+                                   keepdims=False)
+            h, new = step(h, jax.tree.map(at, layers),
+                          jax.tree.map(at, sts), "attn")
+            sts = jax.tree.map(
+                lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, i, 0),
+                sts, new)
+            return (h, sts), None
+        (x, states), _ = jax.lax.scan(
+            body, (x, states), jnp.arange(start, stop, dtype=jnp.int32))
+        return x, states
+
+    new_states = list(states)
+    for i in range(start, stop):
+        x, new_states[i] = step(x, layers[i], states[i], cfg.block_kind(i))
     return x, tuple(new_states)
+
+
+def run_layers_decode(layers, x, states, cur_pos, cfg: ModelConfig,
+                      block_table=None, start: int = 0,
+                      stop: Optional[int] = None):
+    """One-token decode through layers ``start..stop`` of the whole stack.
+    Returns (x, states with those layers updated). ``block_table`` (paged
+    serving) is shared by every attention layer."""
+    def step(h, lp, st, kind):
+        return block_apply_decode(lp, h, st, cur_pos, cfg, kind, block_table)
+    return _run_layer_range(step, layers, x, states, cfg, start, stop)
 
 
 def run_layers_prefill(layers, x, positions, states, cfg: ModelConfig,
-                       kinds: Optional[Tuple[str, ...]] = None, lengths=None,
-                       block_table=None):
-    """Full-sequence pass through a group of layers that also populates the
-    per-layer decode states. Returns (x, new_states)."""
-    if cfg.homogeneous:
-        def body(h, inp):
-            lp, st = inp
-            h, ns = block_apply_prefill(lp, h, positions, st, cfg, "attn",
-                                        lengths, block_table)
-            return h, ns
-        x, new_states = jax.lax.scan(body, x, (layers, states))
-        return x, new_states
-
-    kinds = kinds or tuple(cfg.block_kind(i) for i in range(len(layers)))
-    new_states = []
-    for lp, st, kind in zip(layers, states, kinds):
-        x, ns = block_apply_prefill(lp, x, positions, st, cfg, kind, lengths,
-                                    block_table)
-        new_states.append(ns)
-    return x, tuple(new_states)
+                       lengths=None, block_table=None, start: int = 0,
+                       stop: Optional[int] = None):
+    """Full-sequence pass through layers ``start..stop`` of the whole stack
+    that also populates their decode states. Returns (x, states with those
+    layers updated)."""
+    def step(h, lp, st, kind):
+        return block_apply_prefill(lp, h, positions, st, cfg, kind, lengths,
+                                   block_table)
+    return _run_layer_range(step, layers, x, states, cfg, start, stop)
 
 
 # ---------------------------------------------------------------------------
