@@ -8,10 +8,15 @@ over the paged pool with the fused decode tail and a ``ModeController`` on
 ``default_orchestrator``. The harness owns the clock: it submits each
 request when it is due, calls ``step()``, and after each step notes which
 tokens have become visible on the host.
+
+The model comes from the configuration's file and the reference module it
+names (``bench/references/<name>.py``): its weight tree, its work counts
+and the plain reference the check compares with.
 """
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import gc
 import importlib.util
 import json
@@ -24,7 +29,7 @@ import time
 import jax
 import numpy as np
 
-from bench import loadgen, reference, trace_reduce, weights
+from bench import loadgen, references, trace_reduce, weights
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -62,20 +67,33 @@ def kernel_work(name: str):
                       "bench_kernel_" + name).work
 
 
+#: keys of a configuration file that describe it and reach no program
+DESCRIPTIVE = ("name", "source", "deployment", "reference", "reduced",
+               "assumed", "departures")
+#: keys that make the program's ``SplitConfig``
+SPLIT = ("split_at", "d_bottleneck", "quant_bits")
+
+
 def model_config(c: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
+    """The program's ``ModelConfig`` for a configuration file: every key
+    that names one of its fields (JSON lists become tuples), and the
+    ``SplitConfig`` from ``split_at``, ``d_bottleneck`` and ``quant_bits``.
+    Any key that is neither such a field, nor descriptive, nor read by the
+    configuration's reference module raises: a misspelt key is never served
+    as the default it would fall back to."""
     from repro.configs.base import ModelConfig, SplitConfig
-    return ModelConfig(
-        name=c["name"], arch_type=c["arch_type"], n_layers=c["n_layers"],
-        d_model=c["d_model"], n_heads=c["n_heads"],
-        n_kv_heads=c["n_kv_heads"], d_ff=c["d_ff"],
-        vocab_size=c["vocab_size"], head_dim=c["head_dim"],
-        qkv_bias=c["qkv_bias"], rope_theta=c["rope_theta"], norm=c["norm"],
-        act=c["act"], tie_embeddings=c["tie_embeddings"], dtype=c["dtype"],
-        split=SplitConfig(split_at=c["split_at"],
-                          d_bottleneck=c["d_bottleneck"],
-                          quant_bits=c["quant_bits"]),
-        source=c["source"])
+    fields = {f.name for f in dataclasses.fields(ModelConfig)} - {"split"}
+    known = fields | set(SPLIT) | set(DESCRIPTIVE) \
+        | set(references.load(c["reference"]).READS)
+    unknown = sorted(set(c) - known)
+    if unknown:
+        raise ValueError(f"configuration {c.get('name')!r}: unknown keys "
+                         f"{unknown}")
+    kw = {k: tuple(v) if isinstance(v, list) else v
+          for k, v in c.items() if k in fields}
+    return ModelConfig(**kw, split=SplitConfig(
+        split_at=c["split_at"], d_bottleneck=c["d_bottleneck"],
+        quant_bits=c["quant_bits"]))
 
 
 # ---------------------------------------------------------------------------
@@ -266,7 +284,8 @@ def setup(cfgd: dict, mix: dict, seed: int, *, warm_up: bool = True,
     e = mix["engine"]
     max_ctx = loadgen.max_context(mix)
     n_pages = e.get("n_pages") or e["n_slots"] * -(-max_ctx // e["page_len"])
-    params = weights.make(cfgd, seed)
+    params = weights.make(references.load(cfgd["reference"]).shapes(cfgd),
+                          seed)
     weights.check_layout(params, jax.eval_shape(
         lambda k: SP.init_split_params(k, cfg), jax.random.PRNGKey(0)))
     jax.block_until_ready(params)
@@ -548,10 +567,10 @@ def load_limits(cell_name: str) -> dict:
 
 
 def check(params, cfgd: dict, sessions, control: bool = False) -> dict:
-    """Compare the served tokens with the reference. At each served
-    position, the gap is how far the served token's logit lies below the
-    reference's best. Returns the number of compared positions and, under
-    ``program``, the widest gap and the mean gap over them; with
+    """Compare the served tokens with the configuration's reference. At
+    each served position, the gap is how far the served token's logit lies
+    below the reference's best. Returns the number of compared positions
+    and, under ``program``, the widest gap and the mean gap over them; with
     ``control``, the same two numbers under ``control`` for the token the
     float8 reference puts first at each position."""
     none = {"max_logit_gap": math.inf, "mean_logit_gap": math.inf}
@@ -574,12 +593,13 @@ def check(params, cfgd: dict, sessions, control: bool = False) -> dict:
         served[i, P - 1:P - 1 + len(out)] = out
         mask[i, P - 1:P - 1 + len(out)] = True
     qb = 512 if cfgd["n_heads"] * T * n <= 16 * 8192 * 4 else 256
+    ref = references.load(cfgd["reference"])
     probe = None
     if control:
-        _, _, _, probe = reference.run(params, cfgd, toks, modes, served,
-                                       prec="fp8", qb=qb)
-    best, got, probed, _ = reference.run(params, cfgd, toks, modes, served,
-                                         probe, qb=qb)
+        _, _, _, probe = ref.run(params, cfgd, toks, modes, served,
+                                 prec="fp8", qb=qb)
+    best, got, probed, _ = ref.run(params, cfgd, toks, modes, served, probe,
+                                   qb=qb)
 
     def gaps(logit):
         gap = (best - logit)[mask]

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 from collections import defaultdict
 
-from bench import peaks
+from bench import peaks, references
 
 
 def stretch_ticks(ctx):
@@ -37,16 +37,15 @@ def stretch_prompts(ctx):
 
 
 def matmul_params(cfg):
-    """Parameters a token's layers multiply by (no embedding, no head)."""
-    d, hd, ff = cfg["d_model"], cfg["head_dim"], cfg["d_ff"]
-    nq, nkv = cfg["n_heads"], cfg["n_kv_heads"]
-    per_layer = d * nq * hd * 2 + 2 * d * nkv * hd + 3 * d * ff
-    return cfg["n_layers"] * per_layer
+    """Parameters a token's layers multiply by (no embedding, no head), as
+    the configuration's reference module counts them."""
+    return references.load(cfg["reference"]).matmul_params(cfg)
 
 
 def attention_flops(cfg, rows):
-    """q.k and p.v of one query over ``rows`` keys, in every layer."""
-    return 4 * cfg["n_heads"] * cfg["head_dim"] * rows * cfg["n_layers"]
+    """q.k and p.v of one query over ``rows`` keys, in every layer, as the
+    configuration's reference module counts them."""
+    return references.load(cfg["reference"]).attention_flops(cfg, rows)
 
 
 def kernel_share(ctx, kernel, calls):

@@ -1,4 +1,7 @@
-"""Plain reference of a llama-family split model, for the correctness check.
+"""Plain reference of a llama-family split model, for the correctness check,
+with the model's weight tree and the work a token does in it. A
+configuration names this module by ``"reference": "llama_split"``; the
+harness finds it as ``bench/references/llama_split.py``.
 
 Straightforward ``jax.numpy`` in float32 with matmuls at ``HIGHEST``
 precision: embedding, pre-norm blocks (RMSNorm, GQA attention with
@@ -28,6 +31,62 @@ import numpy as np
 
 HI = jax.lax.Precision.HIGHEST
 NEG = -1e30
+
+#: configuration keys this module reads that are not ``ModelConfig`` fields
+READS = ("norm_eps",)
+
+
+def shapes(cfg: dict) -> dict:
+    """The weight tree, by name: each leaf (kind, shape, scale) for
+    ``weights.make``, laid out as the program's ``init_split_params``.
+
+    Scales: embedding N(0, 1); every projection N(0, 1/fan_in); norm scales
+    1 + N(0, 0.1^2) and q/k/v biases N(0, 0.1^2), so that no term of the
+    forward pass is an identity."""
+    L, d, V = cfg["n_layers"], cfg["d_model"], cfg["vocab_size"]
+    nq, nkv, hd, ff = (cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"],
+                       cfg["d_ff"])
+
+    def dense(i, o, bias=False):
+        p = {"w": ("normal", (L, i, o), i ** -0.5)}
+        if bias:
+            p["b"] = ("bias", (L, o), 0.1)
+        return p
+
+    bias = cfg["qkv_bias"]
+    tree = {
+        "embed": {"table": ("normal", (V, d), 1.0)},
+        "layers": {
+            "norm1": {"scale": ("scale", (L, d), 0.1)},
+            "mix": {"wq": dense(d, nq * hd, bias), "wk": dense(d, nkv * hd, bias),
+                    "wv": dense(d, nkv * hd, bias), "wo": dense(nq * hd, d)},
+            "norm2": {"scale": ("scale", (L, d), 0.1)},
+            "mlp": {"w_gate": dense(d, ff), "w_up": dense(d, ff),
+                    "w_down": dense(ff, d)},
+        },
+        "final_norm": {"scale": ("scale", (d,), 0.1)},
+        "bneck_modes": tuple(
+            {"norm": {"scale": ("scale", (d,), 0.1)},
+             "down": {"w": ("normal", (d, w), d ** -0.5)},
+             "up": {"w": ("normal", (w, d), w ** -0.5)}}
+            for w in ([cfg["d_bottleneck"]] if cfg["d_bottleneck"] else [])),
+    }
+    if not cfg["tie_embeddings"]:
+        tree["lm_head"] = {"w": ("normal", (d, V), d ** -0.5)}
+    return tree
+
+
+def matmul_params(cfg: dict) -> int:
+    """Parameters a token's layers multiply by (no embedding, no head)."""
+    d, hd, ff = cfg["d_model"], cfg["head_dim"], cfg["d_ff"]
+    nq, nkv = cfg["n_heads"], cfg["n_kv_heads"]
+    per_layer = d * nq * hd * 2 + 2 * d * nkv * hd + 3 * d * ff
+    return cfg["n_layers"] * per_layer
+
+
+def attention_flops(cfg: dict, rows: int) -> int:
+    """q.k and p.v of one query over ``rows`` keys, in every layer."""
+    return 4 * cfg["n_heads"] * cfg["head_dim"] * rows * cfg["n_layers"]
 
 
 def _fq(x, axis):

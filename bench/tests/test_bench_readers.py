@@ -14,7 +14,8 @@ import pytest
 from bench import harness, layerstats
 
 CFG = {"n_layers": 2, "d_model": 128, "n_heads": 4, "n_kv_heads": 2,
-       "head_dim": 32, "d_ff": 384, "vocab_size": 512}
+       "head_dim": 32, "d_ff": 384, "vocab_size": 512,
+       "reference": "llama_split"}
 
 
 def _session(rid, prompt, admitted, n_tokens):

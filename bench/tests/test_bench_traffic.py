@@ -131,3 +131,40 @@ def test_wall_clock_channel_follows_time():
 def test_static_channel_is_one_gbit():
     tr = loadgen.traces(_mix("longctx-static"), 30)
     assert tr.shape == (1, 1) and tr[0, 0] == 125e6
+
+
+def _earlier_build(mix, seed, seconds, vocab):
+    """``loadgen.build`` as the accepted cells' schedules were drawn,
+    written out, so that an edit of the generator that moves one draw of
+    chat or long context fails here."""
+    rng = np.random.default_rng(seed)
+    if mix["loop"] == "open":
+        t = loadgen.arrivals(mix["rate_per_s"], mix.get("burst"), 0.0,
+                             float(seconds), rng)
+    else:
+        t = [None] * mix["replay_size"]
+    n = len(t)
+    plen = rng.permutation(loadgen.lengths(mix["prompt_len"], n))
+    olen = rng.permutation(loadgen.lengths(mix["output_len"], n))
+    n_traces = mix["channel"].get("n_traces", 1)
+    ues = rng.permutation(max(n, n_traces)) % n_traces
+    src = list(range(n))
+    if mix["loop"] == "open":
+        wrap = [i for i in range(n) if t[i] >= seconds - mix["lead_in_s"]]
+        t = [float(t[i]) - seconds for i in wrap] + [float(x) for x in t]
+        src = wrap + src
+    return [(r, rng.integers(0, vocab, int(plen[i]), dtype=np.int32),
+             int(olen[i]), t[r], int(ues[i])) for r, i in enumerate(src)]
+
+
+@pytest.mark.parametrize("name", ["chat-mmwave", "longctx-static"])
+@pytest.mark.parametrize("seed", [1, 2 ** 31 + 7, 3140000101, 2 ** 33 + 5])
+def test_schedules_unchanged_draw_for_draw(name, seed):
+    mix = _mix(name)
+    got = loadgen.build(mix, seed, 51, 151936)
+    want = _earlier_build(mix, seed, 51, 151936)
+    assert len(got) == len(want)
+    for it, (rid, prompt, max_new, t_due, ue) in zip(got, want):
+        assert (it.rid, it.max_new, it.t_due, it.ue) == \
+            (rid, max_new, t_due, ue)
+        np.testing.assert_array_equal(it.prompt, prompt)
