@@ -21,6 +21,7 @@ _MODULES: Dict[str, str] = {
     "recurrentgemma-2b": "repro.configs.recurrentgemma_2b",
     "granite-8b": "repro.configs.granite_8b",
     "xlstm-125m": "repro.configs.xlstm_125m",
+    "mellum2-12b-a2.5b": "repro.configs.mellum2_12b",
     "lumos5g-lstm": "repro.configs.lumos5g_lstm",
 }
 

@@ -33,6 +33,10 @@ class SplitConfig:
         return 1 + (1 if self.d_bottleneck else 0) + len(self.extra_modes)
 
 
+#: attention kinds a layer may have (HF ``layer_types`` names)
+LAYER_TYPES = ("sliding_attention", "full_attention")
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -45,8 +49,16 @@ class ModelConfig:
     vocab_size: int
     head_dim: int = 0         # 0 -> d_model // n_heads
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0        # experts whose weights this model holds
     experts_per_tok: int = 0
+    # the router scores ``n_routed_experts`` experts (0: ``n_experts``) and
+    # this model holds ids ``[expert_offset, expert_offset + n_experts)`` of
+    # them: one chip's share of expert parallelism
+    n_routed_experts: int = 0
+    expert_offset: int = 0
+    # storage type of the router's weights (its logits are float32 either
+    # way): float32 for training; a checkpoint served in bf16 stores it so
+    router_dtype: str = "float32"
     # --- attention details ---
     qkv_bias: bool = False
     sliding_window: int = 0   # 0 = full attention
@@ -54,6 +66,19 @@ class ModelConfig:
     norm: str = "rmsnorm"     # rmsnorm | layernorm
     act: str = "silu"         # silu | gelu  (gated MLP)
     tie_embeddings: bool = False
+    # --- attention kind of each layer (HF ``layer_types``), cycled over
+    # layers: "sliding_attention" attends the last ``sliding_window``
+    # positions, "full_attention" every one. Empty: every layer attends
+    # through ``sliding_window`` (0 = full) as a rolling cache.
+    layer_types: Tuple[str, ...] = ()
+    # --- YaRN rotary scaling of the full-attention layers (HF rope_type
+    # "yarn"); ``yarn_factor`` 0 keeps plain rotary tables everywhere.
+    # ``yarn_attention_factor`` 0 means HF's default 0.1 ln(factor) + 1.
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 0.0
     # --- heterogeneous block pattern, cycled over layers ---
     # entries: "attn" | "rglru" | "slstm" | "mlstm"
     block_pattern: Tuple[str, ...] = ("attn",)
@@ -77,11 +102,40 @@ class ModelConfig:
             object.__setattr__(
                 self, "split",
                 dataclasses.replace(self.split, split_at=self.n_layers // 2))
+        bad = set(self.layer_types) - set(LAYER_TYPES)
+        if bad:
+            raise ValueError(f"unknown layer types {sorted(bad)}; known: "
+                             f"{LAYER_TYPES}")
+        if self.n_experts and \
+                self.expert_offset + self.n_experts > self.routed_experts:
+            raise ValueError(
+                f"experts [{self.expert_offset}, "
+                f"{self.expert_offset + self.n_experts}) are not among the "
+                f"{self.routed_experts} the router scores")
 
     # ---- derived quantities -------------------------------------------------
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def routed_experts(self) -> int:
+        """Experts the router scores (all of them, held here or not)."""
+        return self.n_routed_experts or self.n_experts
+
+    @property
+    def per_layer_attention(self) -> bool:
+        """Whether attention windows or rotary tables differ by layer: they
+        then ride through the layer scan as arrays (``layer_meta``)."""
+        return bool(self.layer_types) or bool(self.yarn_factor)
+
+    def layer_window(self, layer: int) -> int:
+        """Keys a query at position p attends on ``layer``: p - w + 1 .. p
+        (0: all of them)."""
+        if not self.layer_types:
+            return self.sliding_window or self.local_window
+        kind = self.layer_types[layer % len(self.layer_types)]
+        return self.sliding_window if kind == "sliding_attention" else 0
 
     def block_kind(self, layer: int) -> str:
         return self.block_pattern[layer % len(self.block_pattern)]
@@ -96,6 +150,8 @@ class ModelConfig:
         attn_layers = [k for k in self.block_pattern if k == "attn"]
         if not attn_layers:
             return True  # pure recurrent
+        if self.layer_types:
+            return "full_attention" not in self.layer_types
         if self.sliding_window or self.local_window:
             return True
         return len(set(self.block_pattern)) > 1 and self.local_window > 0
@@ -125,7 +181,8 @@ class ModelConfig:
                 total += 4 * d * d + d * d
             if kind in ("attn", "rglru"):  # blocks followed by an MLP
                 if self.is_moe:
-                    total += self.n_experts * 3 * d * self.d_ff + d * self.n_experts
+                    total += (self.n_experts * 3 * d * self.d_ff
+                              + d * self.routed_experts)
                 elif self.d_ff:
                     total += 3 * d * self.d_ff
         return total

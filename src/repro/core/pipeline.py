@@ -43,6 +43,9 @@ def stack_stages(params, cfg: ModelConfig, n_stages: int = 2):
     if not cfg.homogeneous:
         raise ValueError("pod pipeline requires a homogeneous layer stack; "
                          "hybrid/ssm archs use the tensor-split path instead")
+    if cfg.per_layer_attention:
+        raise ValueError("pod pipeline stages share one attention setting; "
+                         "per-layer windows or rotary tables are not staged")
     L = cfg.n_layers
     assert L % n_stages == 0, (L, n_stages)
     per = L // n_stages

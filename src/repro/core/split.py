@@ -91,7 +91,7 @@ def decoder_apply(params, payload, positions, cfg: ModelConfig, mode: int, *,
                               bits, dtype=T.model_dtype(cfg))
     _, dec = slice_layers(params["layers"], cfg, s)
     x, aux = T.run_layers(dec, x, positions, cfg, train=train,
-                          kinds=_kinds(cfg)[s:])
+                          kinds=_kinds(cfg)[s:], first=s)
     x = T.norm_apply_final(params, x, cfg)
     logits = sharding.constrain(T.lm_logits(params, x, cfg), "logits")
     return logits, aux
@@ -124,8 +124,8 @@ def split_decode_step(params, token, states, cur_pos, cfg: ModelConfig,
     """
     s = cfg.split.split_at
     x = T.embed_tokens(params, token, cfg, None)
-    x, states = T.run_layers_decode(params["layers"], x, states, cur_pos, cfg,
-                                    stop=s)
+    x, states, *_ = T.run_layers_decode(params["layers"], x, states,
+                                        cur_pos, cfg, stop=s)
     B = x.shape[0]
     if mode == 0:
         payload = (x, None)
@@ -134,8 +134,8 @@ def split_decode_step(params, token, states, cur_pos, cfg: ModelConfig,
         payload = bottleneck.encode(params["bneck_modes"][mode - 1], x, bits)
         x = bottleneck.decode(params["bneck_modes"][mode - 1], *payload, bits,
                               dtype=T.model_dtype(cfg))
-    x, states = T.run_layers_decode(params["layers"], x, states, cur_pos, cfg,
-                                    start=s)
+    x, states, *_ = T.run_layers_decode(params["layers"], x, states,
+                                        cur_pos, cfg, start=s)
     pb = bottleneck.mode_payload_bytes(cfg, B, 1, mode)
     if return_tokens:
         return T.decode_tail_tokens(params, x, cfg), states, pb
@@ -146,7 +146,8 @@ def split_decode_step(params, token, states, cur_pos, cfg: ModelConfig,
 
 def split_decode_step_mixed(params, stacked_bank, token, states, positions,
                             cfg: ModelConfig, mode_idx, block_table=None,
-                            mesh=None, return_tokens: bool = False):
+                            mesh=None, return_tokens: bool = False,
+                            with_stats: bool = False):
     """One decode step for a *mixed-mode* continuous batch.
 
     Unlike :func:`split_decode_step`, every batch slot decodes at its own
@@ -173,22 +174,26 @@ def split_decode_step_mixed(params, stacked_bank, token, states, positions,
     new_states); with ``return_tokens`` the fused decode tail
     (``T.decode_tail_tokens``) replaces the logits with argmax int32 tokens
     and the whole tick is two kernels on TPU — boundary + tail — with the
-    f32 logits never touching HBM.
+    f32 logits never touching HBM. ``with_stats`` (a model with experts)
+    adds the MoE stats of both halves third (``T.run_layers_decode``).
     """
     s = cfg.split.split_at
     x = T.embed_tokens(params, token, cfg, None)
-    x, states = T.run_layers_decode(params["layers"], x, states, positions,
-                                    cfg, block_table=block_table, stop=s)
+    x, states, *enc = T.run_layers_decode(
+        params["layers"], x, states, positions, cfg, block_table=block_table,
+        stop=s)
     x = bottleneck.boundary_mixed(stacked_bank, x, mode_idx,
                                   dtype=T.model_dtype(cfg), mesh=mesh)
     x = sharding.constrain_batch(x, mesh)
-    x, states = T.run_layers_decode(params["layers"], x, states, positions,
-                                    cfg, block_table=block_table, start=s)
+    x, states, *dec = T.run_layers_decode(
+        params["layers"], x, states, positions, cfg, block_table=block_table,
+        start=s)
+    stats = [jax.tree.map(jnp.add, enc[0], dec[0])] if with_stats else []
     if return_tokens:
-        return T.decode_tail_tokens(params, x, cfg), states
+        return (T.decode_tail_tokens(params, x, cfg), states, *stats)
     x = T.norm_apply_final(params, x, cfg)
     logits = T.lm_logits(params, x, cfg)
-    return logits, states
+    return (logits, states, *stats)
 
 
 # ---------------------------------------------------------------------------

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.kernels import bottleneck_quant as _bq
 from repro.kernels import boundary_mixed as _bm
 from repro.kernels import dequant_matmul as _dq
+from repro.kernels import moe_gmm as _mg
 from repro.kernels import paged_attention as _pa
 from repro.kernels import rglru_scan as _rs
 from repro.kernels import ref
@@ -299,8 +300,8 @@ def paged_kernel_eligible(*, n_q: int, n_kv: int, hd: int, page_len: int,
     return _route("paged_attention", None, why)[0]
 
 
-def paged_attention_op(q, k_pages, v_pages, block_table, positions, *,
-                       interpret: bool | None = None):
+def paged_attention_op(q, k_pages, v_pages, block_table, positions,
+                       starts=None, *, interpret: bool | None = None):
     """Paged decode attention (dispatcher) — the Pallas kernel, compiled on
     a TPU or interpreted elsewhere. Deliberately NOT jitted itself —
     serving callers invoke it inside a jitted step, like the boundary op,
@@ -308,11 +309,42 @@ def paged_attention_op(q, k_pages, v_pages, block_table, positions, *,
 
     q: [B, nq, hd] (rope applied), ``k_pages``/``v_pages``:
     [n_pages, n_kv, page_len, hd], ``block_table``: [B, nb] arena page ids,
-    ``positions``: [B]. Returns the attention context [B, nq, hd] in
+    ``positions``: [B], ``starts``: optional [B] first attended row of a
+    sliding-window layer. Returns the attention context [B, nq, hd] in
     ``q.dtype`` (pre-``wo``)."""
     interp = not on_tpu() if interpret is None else bool(interpret)
     return _pa.paged_attention(q, k_pages, v_pages, block_table, positions,
-                               interpret=interp)
+                               starts, interpret=interp)
+
+
+def moe_gmm_op(x, w_gate, w_up, w_down, group_sizes, *, act: str = "silu",
+               name: str = "moe_gmm", interpret: bool | None = None):
+    """Grouped expert MLP over rows sorted by held expert (dispatcher).
+    Not jitted itself: the served MoE layer traces it inside its step.
+
+    x: [M, d]; ``w_gate``/``w_up``: [G, d, f], ``w_down``: [G, f, d];
+    ``group_sizes``: [G] int32 rows of each held expert, in order from row
+    0; rows past their sum belong to experts not held and come back zero.
+    Routes to the Pallas kernel on a TPU (or ``interpret=True``); the CPU
+    and widths that are not whole 128-lane tiles take
+    :func:`ref.moe_gmm_ref` (``jax.lax.ragged_dot``). ``name`` tells call
+    sites apart in a profile. Returns [M, d] float32."""
+    M, d = x.shape
+    f = w_gate.shape[2]
+    use_pallas, interp = _route(
+        "moe_gmm", interpret,
+        f"d={d} f={f} not multiples of 128" if d % 128 or f % 128 else "")
+    if not use_pallas:
+        return ref.moe_gmm_ref(x, w_gate, w_up, w_down, group_sizes, act=act)
+    tm = 128 if M <= 4096 else 256
+    Mp = -(-M // tm) * tm
+    xp = jnp.pad(x, ((0, Mp - M), (0, 0)))
+    sizes = jnp.concatenate([group_sizes.astype(jnp.int32),
+                             (Mp - jnp.sum(group_sizes)).astype(
+                                 jnp.int32)[None]])
+    y = _mg.moe_gmm(xp, w_gate, w_up, w_down, sizes, act=act, tm=tm,
+                    tf=128, name=name, interpret=interp)
+    return y[:M]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -31,6 +31,13 @@ cannot quantize away (they are no-op casts).
 
 ``page_len`` must be a multiple of the model dtype's sublane tile (16 rows
 for bf16, 8 for f32) — ``ops.paged_kernel_eligible``.
+
+A sliding-window layer passes ``starts``, each sequence's first attended
+row, as a third scalar-prefetch array: pages wholly below it are skipped
+like pages past the position (their index maps clamp to the first page in
+the window, so no DMA is issued for them), and rows below it are masked in
+the first page read. Without ``starts`` the kernel is the full-attention
+one, unchanged.
 """
 from __future__ import annotations
 
@@ -50,8 +57,10 @@ NEG_INF = -1e30
 _NT = (((1,), (1,)), ((), ()))
 
 
-def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-            acc_scr, *, nb: int, plen: int, n_kv: int, scale: float):
+def _kernel(bt_ref, pos_ref, *refs, nb: int, plen: int, n_kv: int,
+            scale: float):
+    *st_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
+    windowed = bool(st_ref)            # a third scalar array: the starts
     b = pl.program_id(0)
     j = pl.program_id(1)
     pos_b = pos_ref[b]
@@ -72,15 +81,24 @@ def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     # page j holds logical rows [j*plen, (j+1)*plen); skip pages that start
-    # past the current position (page 0 always runs: row 0 <= pos)
-    @pl.when(j * plen <= pos_b)
+    # past the current position (page 0 always runs: row 0 <= pos) and, in
+    # a window, pages that end before its first row
+    live = j * plen <= pos_b
+    if windowed:
+        st_b = st_ref[0][b]
+        live = jnp.logical_and(live, (j + 1) * plen > st_b)
+
+    @pl.when(live)
     def _page():
         t_abs = j * plen + jax.lax.broadcasted_iota(jnp.int32, (1, plen), 1)
+        keep = t_abs <= pos_b
+        if windowed:
+            keep = jnp.logical_and(keep, t_abs >= st_b)
         for h in range(n_kv):                      # static: one kv group
             s = barrier(jax.lax.dot_general(
                 q_ref[0, h], k_ref[0, h], _NT, precision=prec,
                 preferred_element_type=jnp.float32) * scale)   # [g, plen]
-            s = jnp.where(t_abs <= pos_b, s, NEG_INF)
+            s = jnp.where(keep, s, NEG_INF)
             m_old = m_scr[h]                                   # [g, 1]
             m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
             p = barrier(jnp.exp(s - m_new))                    # [g, plen]
@@ -97,14 +115,15 @@ def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
-def paged_attention(q, k_pages, v_pages, block_table, positions, *,
-                    interpret: bool = False):
+def paged_attention(q, k_pages, v_pages, block_table, positions,
+                    starts=None, *, interpret: bool = False):
     """Paged one-token GQA decode attention.
 
     q: [B, nq, hd] (rope already applied), ``k_pages``/``v_pages``:
     [n_pages, n_kv, page_len, hd] arenas with the current token's row
     already written, ``block_table``: [B, nb] int32 arena page ids,
-    ``positions``: [B] int32 absolute positions. Every page id must be a
+    ``positions``: [B] int32 absolute positions, ``starts``: optional [B]
+    int32 first attended row (a sliding window). Every page id must be a
     valid arena index (the pool guarantees this — unallocated table entries
     point at the reserved scratch page). Query head ``i`` reads kv head
     ``i // (nq / n_kv)``. Returns the attention context [B, nq, hd] in
@@ -117,18 +136,27 @@ def paged_attention(q, k_pages, v_pages, block_table, positions, *,
     g = nq // n_kv
     qg = q.reshape(B, n_kv, g, hd)                 # kv-group-major heads
 
-    page = pl.BlockSpec((1, n_kv, plen, hd),
-                        lambda b, j, bt, pos: (bt[b, j], 0, 0, 0))
+    windowed = starts is not None
+    scalars = [block_table, positions] + ([starts] if windowed else [])
+
+    def page_ix(b, j, bt, pos, *st):
+        if windowed:                   # pages below the window: no new DMA
+            j = jnp.maximum(j, st[0][b] // plen)
+        return (bt[b, j], 0, 0, 0)
+
+    def row_ix(b, j, *_):
+        return (b, 0, 0, 0)
+
+    page = pl.BlockSpec((1, n_kv, plen, hd), page_ix)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((1, n_kv, g, hd), lambda b, j, bt, pos: (b, 0, 0, 0)),
+            pl.BlockSpec((1, n_kv, g, hd), row_ix),
             page,
             page,
         ],
-        out_specs=pl.BlockSpec((1, n_kv, g, hd),
-                               lambda b, j, bt, pos: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, n_kv, g, hd), row_ix),
         scratch_shapes=[
             pltpu.VMEM((n_kv, g, 1), jnp.float32),     # running max
             pltpu.VMEM((n_kv, g, 1), jnp.float32),     # running denominator
@@ -142,6 +170,5 @@ def paged_attention(q, k_pages, v_pages, block_table, positions, *,
         out_shape=jax.ShapeDtypeStruct((B, n_kv, g, hd), q.dtype),
         interpret=interpret,
         name="paged_attention",
-    )(block_table.astype(jnp.int32), positions.astype(jnp.int32),
-      qg, k_pages, v_pages)
+    )(*(a.astype(jnp.int32) for a in scalars), qg, k_pages, v_pages)
     return out.reshape(B, nq, hd)

@@ -103,7 +103,8 @@ def boundary_mixed_grouped_ref(xp, down_w, up_w, norm_scale, hid_g, nchunk_g,
     return jnp.concatenate(outs, axis=0)
 
 
-def paged_attention_ref(q, k_pages, v_pages, block_table, positions):
+def paged_attention_ref(q, k_pages, v_pages, block_table, positions,
+                        starts=None):
     """Blocked jnp oracle for ``paged_attention.paged_attention``.
 
     Walks (sequence, page) exactly like the kernel grid — same page-skip
@@ -113,8 +114,9 @@ def paged_attention_ref(q, k_pages, v_pages, block_table, positions):
     sub-f32 dtypes (bf16); f32 matches to a few ulp (the barriers are no-op
     casts there and cannot quantize away XLA's fusion freedom).
     q: [B, nq, hd]; ``k_pages``/``v_pages``: [n_pages, n_kv, page_len, hd];
-    ``block_table``: [B, nb]; ``positions``: [B] (concrete host values —
-    they steer the python page loop). Returns [B, nq, hd] in ``q.dtype``.
+    ``block_table``: [B, nb]; ``positions``: [B] and optional ``starts``
+    [B], each sequence's first attended row (concrete host values — they
+    steer the python page loop). Returns [B, nq, hd] in ``q.dtype``.
     Test-scale only (python loop over sequences, pages and kv heads).
     """
     import math
@@ -133,6 +135,7 @@ def paged_attention_ref(q, k_pages, v_pages, block_table, positions):
     outs = []
     for b in range(B):
         pos_b = int(positions[b])
+        st_b = 0 if starts is None else int(starts[b])
         qg = q[b].reshape(n_kv, g, hd)
         heads = []
         for h in range(n_kv):
@@ -140,14 +143,17 @@ def paged_attention_ref(q, k_pages, v_pages, block_table, positions):
             l = jnp.zeros((g, 1), jnp.float32)
             acc = jnp.zeros((g, hd), jnp.float32)
             for j in range(nb):
-                if j * plen > pos_b:
+                if j * plen > pos_b or (j + 1) * plen <= st_b:
                     continue
                 page = block_table[b, j]
                 s = barrier(jnp.dot(qg[h], k_pages[page, h].T,
                                     preferred_element_type=jnp.float32)
                             * scale)
                 t_abs = j * plen + jnp.arange(plen)[None, :]
-                s = jnp.where(t_abs <= pos_b, s, NEG_INF)
+                keep = t_abs <= pos_b
+                if starts is not None:
+                    keep = keep & (t_abs >= st_b)
+                s = jnp.where(keep, s, NEG_INF)
                 m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
                 p = barrier(jnp.exp(s - m_new))
                 corr = barrier(jnp.exp(m - m_new))
@@ -159,6 +165,22 @@ def paged_attention_ref(q, k_pages, v_pages, block_table, positions):
             heads.append((acc / l).astype(dt))
         outs.append(jnp.concatenate(heads, axis=0))
     return jnp.stack(outs)
+
+
+def moe_gmm_ref(x, w_gate, w_up, w_down, group_sizes, *, act="silu"):
+    """jnp twin of ``moe_gmm.moe_gmm``: rows sorted by held expert, group
+    ``g`` (``group_sizes[g]`` rows from the end of group g - 1) through
+    expert g's gated MLP with the kernel's casts (f32 products, the
+    activation product rounded to ``x.dtype`` before the down-projection);
+    rows past the groups come back zero. Returns [M, d] float32."""
+    gs = group_sizes.astype(jnp.int32)
+    h = jax.lax.ragged_dot(x, w_gate, gs, preferred_element_type=jnp.float32)
+    u = jax.lax.ragged_dot(x, w_up, gs, preferred_element_type=jnp.float32)
+    a = jax.nn.silu(h) if act == "silu" else jax.nn.gelu(h)
+    a = (a * u).astype(x.dtype)
+    y = jax.lax.ragged_dot(a, w_down, gs, preferred_element_type=jnp.float32)
+    held = jnp.arange(x.shape[0]) < jnp.sum(gs)
+    return jnp.where(held[:, None], y, 0.0)
 
 
 def dequant_matmul_ref(codes, scales, w, out_dtype=jnp.bfloat16):

@@ -55,6 +55,22 @@ def _gqa_out(probs, v):
     return out.reshape(B, S, n_kv * g * v.shape[-1])
 
 
+def _windowed(mask, i, j, window):
+    """``mask`` restricted to keys ``j`` inside the sliding window of
+    queries ``i`` (``j > i - window``). A Python int window is the one
+    window of the whole model (0: none); an array is one layer's own,
+    scanned over the layers (0: a full layer)."""
+    if isinstance(window, int):
+        return mask & (j > i - window) if window else mask
+    return mask & ((window <= 0) | (j > i - window))
+
+
+def window_start(pos, window):
+    """First row a query at ``pos`` attends through ``window`` (0 when the
+    window is 0)."""
+    return jnp.where(window > 0, jnp.maximum(pos - window + 1, 0), 0)
+
+
 # sequences at or above this length use the blocked online-softmax path
 # (bounded memory — the pure-JAX analogue of flash/splash attention, which is
 # what a real TPU deployment would run for 32k prefill)
@@ -67,9 +83,7 @@ def _dense_attention(q, k, v, positions, hd, window):
     scores = _gqa_scores(q, k) / math.sqrt(hd)   # [B,kv,G,S,T] fp32
     i = positions[:, None, None, :, None]        # query pos
     j = positions[:, None, None, None, :]        # key pos
-    mask = j <= i
-    if window:
-        mask &= j > i - window
+    mask = _windowed(j <= i, i, j, window)
     scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     return _gqa_out(probs, v)
@@ -101,9 +115,7 @@ def _blocked_attention(q, k, v, positions, hd, window,
                            k_j.astype(jnp.float32)) * scale
             i_ = pq_i[:, None, None, :, None]
             j_ = pk_j[:, None, None, None, :]
-            mask = j_ <= i_
-            if window:
-                mask &= j_ > i_ - window
+            mask = _windowed(j_ <= i_, i_, j_, window)
             s = jnp.where(mask, s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             p = jnp.exp(s - m_new[..., None])
@@ -130,15 +142,17 @@ def _blocked_attention(q, k, v, positions, hd, window,
 
 
 def full_attention(p, x, positions, *, n_q: int, n_kv: int, hd: int,
-                   rope_theta: float, window: int = 0):
+                   rope_theta: float, window=0, rope=None):
     """Train / prefill path: full causal (optionally sliding-window) attention.
 
-    x: [B, S, d]; positions: [B, S] absolute token positions.
+    x: [B, S, d]; positions: [B, S] absolute token positions. ``window``
+    and ``rope`` (a rotary table, ``layers.apply_rope``) may be one layer's
+    own arrays.
     """
     S = x.shape[1]
     q, k, v = _project_qkv(p, x, n_q, n_kv, hd)
-    q = apply_rope(q, positions, rope_theta)
-    k = apply_rope(k, positions, rope_theta)
+    q = apply_rope(q, positions, rope_theta, rope)
+    k = apply_rope(k, positions, rope_theta, rope)
 
     if S >= BLOCKED_ATTN_THRESHOLD and S % _BLOCK_Q == 0 \
             and S % _BLOCK_K == 0:
@@ -149,8 +163,8 @@ def full_attention(p, x, positions, *, n_q: int, n_kv: int, hd: int,
 
 
 def prefill_attention(p, x, positions, cache, *, n_q: int, n_kv: int,
-                      hd: int, rope_theta: float, window: int = 0,
-                      lengths=None):
+                      hd: int, rope_theta: float, window=0, lengths=None,
+                      rope=None):
     """Full-sequence prefill that also populates the decode cache.
 
     Runs causal (optionally sliding-window) attention over the whole prompt
@@ -167,8 +181,8 @@ def prefill_attention(p, x, positions, cache, *, n_q: int, n_kv: int,
     B, S = x.shape[:2]
     clen = cache["k"].shape[1]
     q, k, v = _project_qkv(p, x, n_q, n_kv, hd)
-    q = apply_rope(q, positions, rope_theta)
-    k = apply_rope(k, positions, rope_theta)
+    q = apply_rope(q, positions, rope_theta, rope)
+    k = apply_rope(k, positions, rope_theta, rope)
     if lengths is None:
         lengths = jnp.full((B,), S, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
@@ -188,8 +202,9 @@ def prefill_attention(p, x, positions, cache, *, n_q: int, n_kv: int,
     # decode can only ever see the last ``clen`` positions, so cap the
     # prefill window to the cache (clen == window for SWA archs by
     # construction; full-attention archs rely on the engine's capacity rule
-    # to keep S <= clen)
-    w_eff = min(window, clen) if window else window
+    # to keep S <= clen); a per-layer window array never rolls the cache
+    w_eff = min(window, clen) if isinstance(window, int) and window \
+        else window
     if S >= BLOCKED_ATTN_THRESHOLD and S % _BLOCK_Q == 0 \
             and S % _BLOCK_K == 0:
         out = _blocked_attention(q, k_att, v_att, positions, hd, w_eff)
@@ -251,7 +266,7 @@ def init_cache(batch: int, n_kv: int, hd: int, cache_len: int,
 
 def paged_prefill_attention(p, x, positions, arena, block_table, *,
                             n_q: int, n_kv: int, hd: int, rope_theta: float,
-                            lengths=None):
+                            lengths=None, window=0, rope=None):
     """Full-sequence prefill that scatters K/V rows through a block table
     into a paged arena instead of ``mod(pos, cache_len)`` rolling slots.
 
@@ -262,22 +277,24 @@ def paged_prefill_attention(p, x, positions, arena, block_table, *,
     out-of-bounds page index and are dropped by the scatter, mirroring the
     dense prefill's drop trick. Attention itself never reads the cache, so
     the output is identical to :func:`prefill_attention` on the same prompt.
+    A sliding-window layer (``window``) masks keys behind its window; every
+    row still goes to the arena, whose table all layers share.
     """
     B, S = x.shape[:2]
     n_pages, plen = arena["k"].shape[0], arena["k"].shape[2]
     nb = block_table.shape[1]
     q, k, v = _project_qkv(p, x, n_q, n_kv, hd)
-    q = apply_rope(q, positions, rope_theta)
-    k = apply_rope(k, positions, rope_theta)
+    q = apply_rope(q, positions, rope_theta, rope)
+    k = apply_rope(k, positions, rope_theta, rope)
     if lengths is None:
         lengths = jnp.full((B,), S, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
 
     if S >= BLOCKED_ATTN_THRESHOLD and S % _BLOCK_Q == 0 \
             and S % _BLOCK_K == 0:
-        out = _blocked_attention(q, k, v, positions, hd, 0)
+        out = _blocked_attention(q, k, v, positions, hd, window)
     else:
-        out = _dense_attention(q, k, v, positions, hd, 0)
+        out = _dense_attention(q, k, v, positions, hd, window)
 
     valid = jnp.arange(S)[None, :] < lengths[:, None]             # [B, S]
     pg_ix = jnp.clip(positions // plen, 0, nb - 1)
@@ -290,7 +307,8 @@ def paged_prefill_attention(p, x, positions, arena, block_table, *,
 
 
 def paged_decode_attention(p, x, arena, block_table, cur_pos, *, n_q: int,
-                           n_kv: int, hd: int, rope_theta: float):
+                           n_kv: int, hd: int, rope_theta: float, window=0,
+                           rope=None):
     """One-token decode against a paged arena through a block table.
 
     x: [B, 1, d]; cur_pos: [B] per-sequence absolute positions; ``arena``
@@ -302,6 +320,9 @@ def paged_decode_attention(p, x, arena, block_table, cur_pos, *, n_q: int,
     is absolute position ``t``; full attention never wraps — and applies
     the exact dense-path score/mask/softmax ops, so on equal logical
     capacity the output is bit-identical to :func:`decode_attention`.
+    A sliding-window layer (``window``, a layer's own array or a nonzero
+    int) attends rows ``pos - window + 1 .. pos`` only: the kernel skips
+    the pages wholly below the window.
     Returns (out [B,1,d], updated arena).
     """
     B = x.shape[0]
@@ -309,8 +330,10 @@ def paged_decode_attention(p, x, arena, block_table, cur_pos, *, n_q: int,
     nb = block_table.shape[1]
     q, k, v = _project_qkv(p, x, n_q, n_kv, hd)
     pos = jnp.asarray(cur_pos, dtype=jnp.int32).reshape(B, 1)
-    q = apply_rope(q, pos, rope_theta)
-    k = apply_rope(k, pos, rope_theta)
+    q = apply_rope(q, pos, rope_theta, rope)
+    k = apply_rope(k, pos, rope_theta, rope)
+    starts = (None if isinstance(window, int) and not window
+              else window_start(pos[:, 0], window))
 
     pg = block_table[jnp.arange(B), jnp.clip(pos[:, 0] // plen, 0, nb - 1)]
     row = jnp.mod(pos[:, 0], plen)
@@ -321,7 +344,7 @@ def paged_decode_attention(p, x, arena, block_table, cur_pos, *, n_q: int,
     if K.paged_kernel_eligible(n_q=n_q, n_kv=n_kv, hd=hd, page_len=plen,
                                dtype=q.dtype):
         ctx = K.paged_attention_op(q[:, 0], new_arena["k"], new_arena["v"],
-                                   block_table, pos[:, 0])
+                                   block_table, pos[:, 0], starts)
         out = ctx.reshape(B, 1, n_q * hd).astype(x.dtype)
     else:
         def logical(a):                # [B, nb, n_kv, plen, hd] -> rows
@@ -332,6 +355,8 @@ def paged_decode_attention(p, x, arena, block_table, cur_pos, *, n_q: int,
         t = jnp.arange(nb * plen)
         n_fill = jnp.minimum(pos[:, 0] + 1, nb * plen)
         written = t[None, :] < n_fill[:, None]            # [B, T]
+        if starts is not None:
+            written &= t[None, :] >= starts[:, None]
         scores = jnp.where(written[:, None, None, None, :], scores, NEG_INF)
         probs = jax.nn.softmax(scores, axis=-1)
         out = _gqa_out(probs, cv).astype(x.dtype)
@@ -339,12 +364,15 @@ def paged_decode_attention(p, x, arena, block_table, cur_pos, *, n_q: int,
 
 
 def decode_attention(p, x, cache, cur_pos, *, n_q: int, n_kv: int, hd: int,
-                     rope_theta: float, window: int = 0):
+                     rope_theta: float, window=0, rope=None):
     """One-token decode against the cache.
 
     x: [B, 1, d]; cur_pos: scalar int32 (all sequences aligned, as in
     synchronous batched serving) or a [B] vector of per-sequence absolute
     positions (continuous batching: each slot is at its own depth).
+    An int ``window`` is the model's one window, the cache's length; a
+    layer's own window array masks keys behind it in a cache that never
+    wraps (slot t holds position t).
     Returns (out [B,1,d], updated cache).
     """
     B = x.shape[0]
@@ -354,8 +382,8 @@ def decode_attention(p, x, cache, cur_pos, *, n_q: int, n_kv: int, hd: int,
     ragged = cur_pos.ndim == 1
     pos = cur_pos.reshape(B, 1) if ragged \
         else jnp.full((B, 1), cur_pos, dtype=jnp.int32)
-    q = apply_rope(q, pos, rope_theta)
-    k = apply_rope(k, pos, rope_theta)
+    q = apply_rope(q, pos, rope_theta, rope)
+    k = apply_rope(k, pos, rope_theta, rope)
 
     slot = jnp.mod(pos[:, 0], cache_len) if ragged \
         else jnp.mod(cur_pos, cache_len)          # rolling for SWA
@@ -394,6 +422,8 @@ def decode_attention(p, x, cache, cur_pos, *, n_q: int, n_kv: int, hd: int,
     t = jnp.arange(cache_len)
     n_fill = jnp.minimum(pos[:, 0] + 1, cache_len)    # valid slots per seq
     written = t[None, :] < n_fill[:, None]            # [B, T]
+    if not isinstance(window, int):
+        written &= t[None, :] >= window_start(pos, window)
     scores = jnp.where(written[:, None, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = _gqa_out(probs, cv).astype(x.dtype)

@@ -106,15 +106,61 @@ def mlp_apply(p, x, act: str = "silu"):
 # rotary position embeddings
 # ---------------------------------------------------------------------------
 
-def apply_rope(x, positions, theta: float = 10_000.0):
-    """x: [..., S, H, hd]; positions: broadcastable to [..., S]."""
+def rope_inv_freq(hd: int, theta: float, *, yarn_factor: float = 0.0,
+                  original_max: int = 0, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0):
+    """Inverse rotary frequencies ``theta ** (-2i / hd)`` of the ``hd // 2``
+    channel pairs, as float64 numpy. With ``yarn_factor`` they follow HF
+    transformers' YaRN (``_compute_yarn_parameters``): pairs that turn
+    fewer than ``beta_slow`` times over ``original_max`` positions are
+    interpolated (frequency / factor), pairs that turn more than
+    ``beta_fast`` times keep their frequency, and a linear ramp blends the
+    pairs between, its ends rounded outwards (floor, ceil)."""
+    import numpy as np
+    half = hd // 2
+    inv = theta ** (-np.arange(0, hd, 2, dtype=np.float64) / hd)
+    if not yarn_factor:
+        return inv
+
+    def corr_dim(rotations):
+        return (hd * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(beta_fast)), 0)
+    high = min(math.ceil(corr_dim(beta_slow)), hd - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low),
+                   0.0, 1.0)
+    extrapolate = 1.0 - ramp
+    return inv / yarn_factor * (1.0 - extrapolate) + inv * extrapolate
+
+
+def yarn_attention_factor(factor: float, given: float = 0.0) -> float:
+    """YaRN's scale of cos and sin: ``given``, or HF's default
+    0.1 ln(factor) + 1."""
+    return given or (0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0)
+
+
+def apply_rope(x, positions, theta: float = 10_000.0, table=None):
+    """x: [..., S, H, hd]; positions: broadcastable to [..., S].
+
+    ``table`` = (inv_freq [hd // 2], scale): the layer's own rotary table
+    (arrays, so that it may differ between the layers of one scan); cos
+    and sin are multiplied by ``scale``. None: plain ``theta`` table."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32)
-                    * (math.log(theta) / half))
+    if table is None:
+        freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32)
+                        * (math.log(theta) / half))
+        scale = None
+    else:
+        freqs, scale = table
     ang = positions[..., None].astype(jnp.float32) * freqs  # [..., S, half]
     cos = jnp.cos(ang)[..., None, :]   # [..., S, 1, half]
     sin = jnp.sin(ang)[..., None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate([xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin],

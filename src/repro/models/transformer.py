@@ -16,6 +16,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import sharding
@@ -24,8 +25,9 @@ from repro.models.attention import (attn_init, decode_attention, full_attention,
                                     paged_prefill_attention, prefill_attention)
 from repro.models.layers import (dense_apply, dense_init, embed_apply,
                                  embed_init, mlp_apply, mlp_init, norm_apply,
-                                 norm_init)
-from repro.models.moe import moe_apply, moe_init
+                                 norm_init, rope_inv_freq,
+                                 yarn_attention_factor)
+from repro.models.moe import moe_apply, moe_held_apply, moe_init
 from repro.models.moe_ep import moe_apply_ep, moe_supports_ep
 from repro.models.rglru import (rglru_full, rglru_init, rglru_prefill,
                                 rglru_state_init, rglru_step)
@@ -65,13 +67,19 @@ def block_init(key, cfg: ModelConfig, kind: str):
         p["norm2"] = norm_init(cfg.d_model, cfg.norm, dtype=dt)
         if cfg.is_moe:
             p["mlp"] = moe_init(k2, cfg.d_model, cfg.d_ff, cfg.n_experts,
-                                dtype=dt)
+                                dtype=dt, n_routed=cfg.routed_experts,
+                                router_dtype=_DTYPES[cfg.router_dtype])
         else:
             p["mlp"] = mlp_init(k2, cfg.d_model, cfg.d_ff, dtype=dt)
     return p
 
 
 def _attn_window(cfg: ModelConfig) -> int:
+    """The one window of every attention layer, which sizes a rolling
+    cache; 0 where layers differ (``layer_types``): their windows then ride
+    the layer scan as arrays (``layer_meta``) over caches that never wrap."""
+    if cfg.layer_types:
+        return 0
     return cfg.sliding_window or cfg.local_window
 
 
@@ -80,8 +88,51 @@ def full_attention_arch(cfg: ModelConfig) -> bool:
     is addressed by absolute position, so serving must keep
     ``prompt_len + max_new_tokens <= cache_len`` or the rolling write
     (``pos % cache_len``) silently evicts early prompt context."""
-    return (not _attn_window(cfg)) and any(
-        cfg.block_kind(i) == "attn" for i in range(cfg.n_layers))
+    return any(cfg.block_kind(i) == "attn" and not cfg.layer_window(i)
+               for i in range(cfg.n_layers))
+
+
+def layer_meta(cfg: ModelConfig):
+    """Per-layer attention settings of a stack whose layers differ, as
+    arrays the layer scan indexes (None when every layer is alike):
+    ``window`` [L] int32 (0: full), and the rotary table, ``inv_freq``
+    [L, head_dim // 2] float32 and ``rope_scale`` [L] float32 — YaRN's on
+    full layers when ``yarn_factor`` is set, the plain ``rope_theta`` table
+    elsewhere."""
+    if not cfg.per_layer_attention:
+        return None
+    windows = [cfg.layer_window(i) for i in range(cfg.n_layers)]
+    plain = rope_inv_freq(cfg.head_dim, cfg.rope_theta)
+    yarn = plain if not cfg.yarn_factor else rope_inv_freq(
+        cfg.head_dim, cfg.rope_theta, yarn_factor=cfg.yarn_factor,
+        original_max=cfg.yarn_original_max_position,
+        beta_fast=cfg.yarn_beta_fast, beta_slow=cfg.yarn_beta_slow)
+    scale = 1.0 if not cfg.yarn_factor else yarn_attention_factor(
+        cfg.yarn_factor, cfg.yarn_attention_factor)
+    return {
+        "window": np.asarray(windows, np.int32),
+        "inv_freq": np.stack([yarn if w == 0 else plain for w in windows]
+                             ).astype(np.float32),
+        "rope_scale": np.asarray([scale if w == 0 else 1.0 for w in windows],
+                                 np.float32),
+    }
+
+
+def _attn_settings(cfg: ModelConfig, meta):
+    """(window, rotary table) of one layer: its ``layer_meta`` row, or the
+    model's one window and the plain table."""
+    if meta is None:
+        return _attn_window(cfg), None
+    return meta["window"], (meta["inv_freq"], meta["rope_scale"])
+
+
+def _served_moe(p, h, cfg: ModelConfig, kernel: str = "moe_gmm"):
+    """The served MoE layer (``moe_held_apply``): dropless, over the
+    experts this model holds. ``kernel`` names the expert kernel in a
+    profile: decode's ``moe_gmm``, and ``moe_gmm_prefill`` for whole
+    sequences. Returns (y, stats)."""
+    return moe_held_apply(p, h, k=cfg.experts_per_tok, e0=cfg.expert_offset,
+                          act=cfg.act, kernel=kernel)
 
 
 def check_cache_capacity(cfg: ModelConfig, pos: int, n: int, cache_len: int,
@@ -101,18 +152,21 @@ def check_cache_capacity(cfg: ModelConfig, pos: int, n: int, cache_len: int,
 
 
 def block_apply_full(p, x, positions, cfg: ModelConfig, kind: str,
-                     train: bool = False):
+                     train: bool = False, meta=None):
     """Full-sequence block. Returns (x, aux_loss). ``train`` keeps the
     recurrent families on their remat-friendly ``chunked_scan`` paths (the
     Pallas scan op has no VJP); eval routes them through
-    ``ops.rglru_scan_op``."""
+    ``ops.rglru_scan_op``. Training routes experts with the capacity layer
+    (``moe_apply`` / ``moe_apply_ep``) for its load-balance loss; eval
+    takes the served layer. ``meta``: the layer's ``layer_meta`` row."""
     aux = jnp.zeros((), jnp.float32)
     h = norm_apply(p["norm1"], x, cfg.norm)
     if kind == "attn":
+        window, rope = _attn_settings(cfg, meta)
         mix = full_attention(p["mix"], h, positions, n_q=cfg.n_heads,
                              n_kv=cfg.n_kv_heads, hd=cfg.head_dim,
-                             rope_theta=cfg.rope_theta,
-                             window=_attn_window(cfg))
+                             rope_theta=cfg.rope_theta, window=window,
+                             rope=rope)
     elif kind == "rglru":
         mix = rglru_full(p["mix"], h, act=cfg.act, train=train)
     elif kind == "mlstm":
@@ -124,7 +178,9 @@ def block_apply_full(p, x, positions, cfg: ModelConfig, kind: str,
     x = x + mix
     if "mlp" in p:
         h = norm_apply(p["norm2"], x, cfg.norm)
-        if cfg.is_moe:
+        if cfg.is_moe and not train:
+            m, _ = _served_moe(p["mlp"], h, cfg, "moe_gmm_prefill")
+        elif cfg.is_moe:
             mesh = sharding.ctx_mesh()
             if sharding.ctx_flag("moe_ep") and moe_supports_ep(
                     cfg.n_experts, mesh, h.shape[0], h.shape[1]):
@@ -140,20 +196,24 @@ def block_apply_full(p, x, positions, cfg: ModelConfig, kind: str,
 
 
 def block_apply_decode(p, x, state, cur_pos, cfg: ModelConfig, kind: str,
-                       block_table=None):
-    """One-token decode. Returns (x, new_state). With ``block_table`` the
-    attention state is a paged arena indexed through the table instead of a
-    dense per-slot rolling cache."""
+                       block_table=None, meta=None):
+    """One-token decode. Returns (x, new_state), and in a model with experts
+    the served MoE layer's stats third. With
+    ``block_table`` the attention state is a paged arena indexed through the
+    table instead of a dense per-slot rolling cache. ``meta``: the layer's
+    ``layer_meta`` row."""
     h = norm_apply(p["norm1"], x, cfg.norm)
+    window, rope = _attn_settings(cfg, meta)
     if kind == "attn" and block_table is not None:
         mix, new_state = paged_decode_attention(
             p["mix"], h, state, block_table, cur_pos, n_q=cfg.n_heads,
-            n_kv=cfg.n_kv_heads, hd=cfg.head_dim, rope_theta=cfg.rope_theta)
+            n_kv=cfg.n_kv_heads, hd=cfg.head_dim, rope_theta=cfg.rope_theta,
+            window=window, rope=rope)
     elif kind == "attn":
         mix, new_state = decode_attention(
             p["mix"], h, state, cur_pos, n_q=cfg.n_heads, n_kv=cfg.n_kv_heads,
-            hd=cfg.head_dim, rope_theta=cfg.rope_theta,
-            window=_attn_window(cfg))
+            hd=cfg.head_dim, rope_theta=cfg.rope_theta, window=window,
+            rope=rope)
     elif kind == "rglru":
         mix, new_state = rglru_step(p["mix"], h, state, act=cfg.act)
     elif kind == "mlstm":
@@ -163,33 +223,36 @@ def block_apply_decode(p, x, state, cur_pos, cfg: ModelConfig, kind: str,
     else:
         raise ValueError(kind)
     x = x + mix
+    stats = None
     if "mlp" in p:
         h = norm_apply(p["norm2"], x, cfg.norm)
         if cfg.is_moe:
-            m, _ = moe_apply(p["mlp"], h, k=cfg.experts_per_tok, act=cfg.act)
+            m, stats = _served_moe(p["mlp"], h, cfg)
         else:
             m = mlp_apply(p["mlp"], h, cfg.act)
         x = x + m
-    return x, new_state
+    return (x, new_state, stats) if cfg.is_moe else (x, new_state)
 
 
 def block_apply_prefill(p, x, positions, state, cfg: ModelConfig, kind: str,
-                        lengths=None, block_table=None):
+                        lengths=None, block_table=None, meta=None):
     """Full-sequence block that also populates the decode state (KV cache or
     recurrent carry) — one forward instead of S sequential decode steps.
     Returns (x, new_state). With ``block_table`` the attention rows scatter
-    into a paged arena through the table."""
+    into a paged arena through the table. ``meta``: the layer's
+    ``layer_meta`` row."""
     h = norm_apply(p["norm1"], x, cfg.norm)
+    window, rope = _attn_settings(cfg, meta)
     if kind == "attn" and block_table is not None:
         mix, new_state = paged_prefill_attention(
             p["mix"], h, positions, state, block_table, n_q=cfg.n_heads,
             n_kv=cfg.n_kv_heads, hd=cfg.head_dim, rope_theta=cfg.rope_theta,
-            lengths=lengths)
+            lengths=lengths, window=window, rope=rope)
     elif kind == "attn":
         mix, new_state = prefill_attention(
             p["mix"], h, positions, state, n_q=cfg.n_heads,
             n_kv=cfg.n_kv_heads, hd=cfg.head_dim, rope_theta=cfg.rope_theta,
-            window=_attn_window(cfg), lengths=lengths)
+            window=window, lengths=lengths, rope=rope)
     elif kind == "rglru":
         mix, new_state = rglru_prefill(p["mix"], h, state, act=cfg.act,
                                        lengths=lengths)
@@ -205,9 +268,10 @@ def block_apply_prefill(p, x, positions, state, cfg: ModelConfig, kind: str,
     if "mlp" in p:
         h = norm_apply(p["norm2"], x, cfg.norm)
         if cfg.is_moe:
-            # the plain (non-EP) expert path, matching what decode runs —
-            # routing is per token, so results are identical either way
-            m, _ = moe_apply(p["mlp"], h, k=cfg.experts_per_tok, act=cfg.act)
+            # the served expert layer decode runs too: routing is per token
+            # and nothing is dropped, so a token gets the same experts
+            # whether it is prefilled or decoded
+            m, _ = _served_moe(p["mlp"], h, cfg, "moe_gmm_prefill")
         else:
             m = mlp_apply(p["mlp"], h, cfg.act)
         x = x + m
@@ -360,29 +424,44 @@ def decode_tail_tokens(params, x, cfg: ModelConfig):
 # layer runners (shared by the full model and the split encoder/decoder)
 # ---------------------------------------------------------------------------
 
+def _meta_row(meta, i):
+    """Layer ``i``'s row of ``layer_meta`` (None passes through)."""
+    if meta is None:
+        return None
+    return {k: jnp.asarray(v)[i] for k, v in meta.items()}
+
+
 def run_layers(layers, x, positions, cfg: ModelConfig, *, train: bool,
-               kinds: Optional[Tuple[str, ...]] = None):
+               kinds: Optional[Tuple[str, ...]] = None, first: int = 0):
     """Full-sequence pass through a group of layers.
 
-    ``layers``: stacked pytree (homogeneous) or tuple of per-layer pytrees.
+    ``layers``: stacked pytree (homogeneous) or tuple of per-layer pytrees,
+    the model's layers ``first ..`` (their per-layer attention settings).
     Returns (x, aux_loss_sum).
     """
+    meta = layer_meta(cfg)
     if cfg.homogeneous:
-        def body(carry, lp):
+        if meta is not None:
+            n = jax.tree.leaves(layers)[0].shape[0]
+            meta = {k: v[first:first + n] for k, v in meta.items()}
+
+        def body(carry, xs):
+            lp, m = xs
             h, aux = carry
             h = sharding.constrain(h, "resid")
-            h, a = block_apply_full(lp, h, positions, cfg, "attn", train)
+            h, a = block_apply_full(lp, h, positions, cfg, "attn", train, m)
             return (h, aux + a), None
         f = jax.checkpoint(body) if train else body
-        (x, aux), _ = jax.lax.scan(f, (x, jnp.zeros((), jnp.float32)), layers)
+        (x, aux), _ = jax.lax.scan(f, (x, jnp.zeros((), jnp.float32)),
+                                   (layers, meta))
         return x, aux
 
     kinds = kinds or tuple(cfg.block_kind(i) for i in range(len(layers)))
     aux = jnp.zeros((), jnp.float32)
-    for lp, kind in zip(layers, kinds):
+    for j, (lp, kind) in enumerate(zip(layers, kinds)):
         x = sharding.constrain(x, "resid")
         fn = functools.partial(block_apply_full, cfg=cfg, kind=kind,
-                               train=train)
+                               train=train, meta=_meta_row(meta, first + j))
         if train:
             fn = jax.checkpoint(fn)
         x, a = fn(lp, x, positions)
@@ -391,10 +470,13 @@ def run_layers(layers, x, positions, cfg: ModelConfig, *, train: bool,
 
 
 def _run_layer_range(step, layers, x, states, cfg: ModelConfig, start: int,
-                     stop: Optional[int]):
-    """Run ``step(h, layer_params, layer_state, kind) -> (h, new_state)``
-    over layers ``start..stop`` (default: to the end) of the WHOLE stack and
-    return (x, states) with those layers' states replaced.
+                     stop: Optional[int], acc=None):
+    """Run ``step(h, layer_params, layer_state, kind, meta) -> (h,
+    new_state)`` over layers ``start..stop`` (default: to the end) of the
+    WHOLE stack and return (x, states) with those layers' states replaced.
+    ``meta`` is the layer's ``layer_meta`` row (None when layers are
+    alike). With ``acc`` (a pytree of zeros) ``step`` returns a third value
+    of that structure, summed over the layers and returned third.
 
     Homogeneous stacks scan over the layer indices: each iteration indexes
     layer ``i`` out of the full ``[L, ...]`` params the scan closes over and
@@ -404,36 +486,52 @@ def _run_layer_range(step, layers, x, states, cfg: ModelConfig, start: int,
     per-layer pytrees (heterogeneous stacks) loop in Python, where a range
     of a tuple copies nothing."""
     stop = cfg.n_layers if stop is None else stop
+    meta = layer_meta(cfg)
+
+    def add(tot, out):
+        if acc is None:
+            return out[0], out[1], tot
+        return out[0], out[1], jax.tree.map(jnp.add, tot, out[2])
+
     if cfg.homogeneous:
         def body(carry, i):
-            h, sts = carry
+            h, sts, tot = carry
             at = functools.partial(jax.lax.dynamic_index_in_dim, index=i,
                                    keepdims=False)
-            h, new = step(h, jax.tree.map(at, layers),
-                          jax.tree.map(at, sts), "attn")
+            h, new, tot = add(tot, step(h, jax.tree.map(at, layers),
+                                        jax.tree.map(at, sts), "attn",
+                                        _meta_row(meta, i)))
             sts = jax.tree.map(
                 lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, i, 0),
                 sts, new)
-            return (h, sts), None
-        (x, states), _ = jax.lax.scan(
-            body, (x, states), jnp.arange(start, stop, dtype=jnp.int32))
-        return x, states
+            return (h, sts, tot), None
+        (x, states, tot), _ = jax.lax.scan(
+            body, (x, states, acc), jnp.arange(start, stop, dtype=jnp.int32))
+        return (x, states) if acc is None else (x, states, tot)
 
     new_states = list(states)
+    tot = acc
     for i in range(start, stop):
-        x, new_states[i] = step(x, layers[i], states[i], cfg.block_kind(i))
-    return x, tuple(new_states)
+        x, new_states[i], tot = add(tot, step(
+            x, layers[i], states[i], cfg.block_kind(i), _meta_row(meta, i)))
+    return (x, tuple(new_states)) if acc is None \
+        else (x, tuple(new_states), tot)
 
 
 def run_layers_decode(layers, x, states, cur_pos, cfg: ModelConfig,
                       block_table=None, start: int = 0,
                       stop: Optional[int] = None):
     """One-token decode through layers ``start..stop`` of the whole stack.
-    Returns (x, states with those layers updated). ``block_table`` (paged
+    Returns (x, states with those layers updated); in a model with experts
+    also the served MoE layer's stats summed over the layers, third: ``rows`` [B, 1] each token's rows routed to held experts and
+    ``touched``, held experts that got a row. ``block_table`` (paged
     serving) is shared by every attention layer."""
-    def step(h, lp, st, kind):
-        return block_apply_decode(lp, h, st, cur_pos, cfg, kind, block_table)
-    return _run_layer_range(step, layers, x, states, cfg, start, stop)
+    def step(h, lp, st, kind, meta):
+        return block_apply_decode(lp, h, st, cur_pos, cfg, kind, block_table,
+                                  meta)
+    acc = ({"rows": jnp.zeros(x.shape[:-1], jnp.int32),
+            "touched": jnp.zeros((), jnp.int32)} if cfg.is_moe else None)
+    return _run_layer_range(step, layers, x, states, cfg, start, stop, acc)
 
 
 def run_layers_prefill(layers, x, positions, states, cfg: ModelConfig,
@@ -442,9 +540,9 @@ def run_layers_prefill(layers, x, positions, states, cfg: ModelConfig,
     """Full-sequence pass through layers ``start..stop`` of the whole stack
     that also populates their decode states. Returns (x, states with those
     layers updated)."""
-    def step(h, lp, st, kind):
+    def step(h, lp, st, kind, meta):
         return block_apply_prefill(lp, h, positions, st, cfg, kind, lengths,
-                                   block_table)
+                                   block_table, meta)
     return _run_layer_range(step, layers, x, states, cfg, start, stop)
 
 
@@ -494,19 +592,22 @@ def prefill(params, tokens, cfg: ModelConfig, states, lengths=None,
 
 def decode_step(params, token, states, cur_pos, cfg: ModelConfig,
                 embeddings: Optional[jnp.ndarray] = None, block_table=None,
-                return_tokens: bool = False):
+                return_tokens: bool = False, with_stats: bool = False):
     """One new token against the decode state. token: [B,1] (or [B,K,1]
     audio). Returns (logits for the new position, new states); with
     ``return_tokens`` the fused decode tail replaces the logits with argmax
     int32 tokens (shaped like the token input) and the [B, V] logits never
-    materialize."""
+    materialize. ``with_stats`` adds the MoE stats of
+    ``run_layers_decode`` third (a model with experts)."""
     x = embed_tokens(params, token, cfg, None)
-    x, new_states = run_layers_decode(params["layers"], x, states, cur_pos,
-                                      cfg, block_table=block_table)
+    x, new_states, *stats = run_layers_decode(
+        params["layers"], x, states, cur_pos, cfg, block_table=block_table)
     if return_tokens:
-        return decode_tail_tokens(params, x, cfg), new_states
-    x = norm_apply(params["final_norm"], x, cfg.norm)
-    return lm_logits(params, x, cfg), new_states
+        out = decode_tail_tokens(params, x, cfg)
+    else:
+        x = norm_apply(params["final_norm"], x, cfg.norm)
+        out = lm_logits(params, x, cfg)
+    return (out, new_states, *stats) if with_stats else (out, new_states)
 
 
 def lm_loss(logits, labels, mask=None):

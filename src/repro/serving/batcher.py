@@ -211,24 +211,35 @@ def _window_scan_body(cfg: ModelConfig, mesh, *, mixed: bool,
     into the next tick's embed — no separate head/argmax/feedback HLOs and
     no [B, V] f32 logits in HBM. ``fused_tail=False`` keeps the legacy
     logits+argmax body: the equivalence oracle ``tests/test_device_loop.py``
-    pins token streams against."""
+    pins token streams against.
+
+    A model with experts also returns, after the [K, B] token block, the
+    served MoE layer's counts of each tick, summed over the layers: [K, B]
+    rows each slot's token routed to held experts, and [K] held experts
+    that got a row. They come back with the token block, in the same
+    materialization."""
+    stats = cfg.is_moe
+
     def run(params, stacked, tok, states, positions, modes_k, bt):
         def body(carry, modes):
             tok, states, positions = carry
             if mixed:
-                out, new_states = SP.split_decode_step_mixed(
+                out, new_states, *st = SP.split_decode_step_mixed(
                     params, stacked, tok, states, positions, cfg, modes,
-                    block_table=bt, mesh=mesh, return_tokens=fused_tail)
+                    block_table=bt, mesh=mesh, return_tokens=fused_tail,
+                    with_stats=stats)
             else:
-                out, new_states = T.decode_step(
+                out, new_states, *st = T.decode_step(
                     params, tok, states, positions, cfg, block_table=bt,
-                    return_tokens=fused_tail)
+                    return_tokens=fused_tail, with_stats=stats)
             nxt = out if fused_tail else jnp.argmax(out, axis=-1)
             nxt = nxt.astype(jnp.int32).reshape(tok.shape)
-            return (nxt, new_states, positions + 1), nxt
+            ys = (nxt, st[0]["rows"].reshape(-1), st[0]["touched"]) \
+                if stats else (nxt,)
+            return (nxt, new_states, positions + 1), ys
 
-        carry, out = jax.lax.scan(body, (tok, states, positions), modes_k)
-        return (*carry, out)
+        carry, ys = jax.lax.scan(body, (tok, states, positions), modes_k)
+        return (*carry, *ys)
 
     return run
 
@@ -1469,13 +1480,17 @@ class ContinuousBatchingEngine:
         with span("engine.materialize", self._tel,
                   "engine.window_materialize_s", k=k):
             with span("engine.materialize_wait", self._tel):
-                arr = np.asarray(fut.result()[3])    # [K, B, ...]
+                # the token block [K, B, ...] and, with experts, the MoE
+                # counts of the same window, in one transfer
+                arr, *moe = jax.device_get(fut.result()[3:])
             if self._tel is not None:
                 # window wall clock (dispatch -> tokens on host) over k
                 # ticks IS the device loop's inter-token latency, weighted
                 # by the tokens the window produced
                 self._tel.observe("engine.intertoken_s",
                                   (_now() - t_disp) / k, k * len(snapshot))
+            if moe:
+                self._record_moe(snapshot, *moe)
             for slot, sess in snapshot:
                 for i in range(k):
                     tok = arr[i, slot]
@@ -1484,6 +1499,18 @@ class ContinuousBatchingEngine:
                 budget = sess.gen_budget or sess.request.max_new_tokens
                 if len(sess.tokens) >= budget:
                     self.finished.append(sess)
+
+    def _record_moe(self, snapshot, rows, touched):
+        """A window's MoE counts (``_window_scan_body``) onto its sessions,
+        one entry per decoded token, and into the telemetry registry."""
+        for slot, sess in snapshot:
+            sess.moe_rows.extend(int(r) for r in rows[:, slot])
+            sess.moe_touched.extend(int(t) for t in touched)
+        if self._tel is not None:
+            self._tel.inc("moe.rows_here",
+                          int(sum(rows[:, slot].sum() for slot, _ in snapshot)))
+            self._tel.inc("moe.touched_experts", int(touched.sum()))
+            self._tel.inc("moe.decode_ticks", len(touched))
 
     def _materialize_inflight(self):
         if self._inflight is not None:

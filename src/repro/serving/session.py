@@ -67,6 +67,11 @@ class Session:
     #: the admission entry is always present, so a session that never
     #: switched has exactly one entry
     mode_trace: List[Tuple[int, int]] = field(default_factory=list)
+    #: models with experts, one entry per token decoded on the device loop:
+    #: the token's rows routed to experts held here (summed over layers),
+    #: and the held experts that got a row in that tick (all slots)
+    moe_rows: List[int] = field(default_factory=list)
+    moe_touched: List[int] = field(default_factory=list)
     deadline_misses: int = 0           # decode tokens whose simulated
     #                                    transfer blew the latency budget
     escalations: int = 0               # controller deadline escalations

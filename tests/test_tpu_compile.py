@@ -3,7 +3,8 @@
 Nothing runs: each kernel is lowered and compiled by the TPU compiler for a
 chip that is described, not attached, at the widths the served models use
 (qwen2.5-3b for the boundary, the decode tail and paged attention;
-recurrentgemma-2b's ``d_rnn`` for the RG-LRU scan). Interpret-mode parity
+mellum2-12b-a2.5b for the sliding-window paged attention and the grouped
+expert kernel; recurrentgemma-2b's ``d_rnn`` for the RG-LRU scan). Interpret-mode parity
 (``test_kernels.py``, ``test_paged.py``) cannot see what Mosaic refuses:
 unsupported contractions, dynamic slices of loaded values, misaligned tiles.
 
@@ -19,11 +20,13 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.core import bottleneck
 from repro.kernels import boundary_mixed as BM
+from repro.kernels import moe_gmm as MG
 from repro.kernels import ops
 from repro.kernels import paged_attention as PA
 from repro.kernels import rglru_scan as RS
 
 QWEN = get_config("qwen2.5-3b")
+MELLUM = get_config("mellum2-12b-a2.5b")
 RG = get_config("recurrentgemma-2b")
 SLOTS = 32                      # decode rows of one serving tick
 
@@ -107,6 +110,32 @@ def test_paged_attention_compiles(one_chip):
         ((n_pages, nkv, page_len, hd), jnp.bfloat16),
         ((SLOTS, nb), jnp.int32), ((SLOTS,), jnp.int32))
     _assert_kernel(text, "paged_attention")
+
+
+def test_windowed_paged_attention_compiles(one_chip):
+    nq, nkv, hd = MELLUM.n_heads, MELLUM.n_kv_heads, MELLUM.head_dim
+    page_len, slots = 16, 16
+    nb = 512                                          # up to 8k rows
+    n_pages = 2881
+    text = _compiled_text(
+        PA.paged_attention, one_chip, ((slots, nq, hd), jnp.bfloat16),
+        ((n_pages, nkv, page_len, hd), jnp.bfloat16),
+        ((n_pages, nkv, page_len, hd), jnp.bfloat16),
+        ((slots, nb), jnp.int32), ((slots,), jnp.int32),
+        ((slots,), jnp.int32))
+    _assert_kernel(text, "paged_attention")
+
+
+@pytest.mark.parametrize("M,tm", [(128, 128), (16384, 256)],
+                         ids=["decode", "prefill-chunk"])
+def test_moe_gmm_compiles(one_chip, M, tm):
+    d, f, held = MELLUM.d_model, MELLUM.d_ff, 16
+    text = _compiled_text(
+        lambda x, g, u, dn, sz: MG.moe_gmm(x, g, u, dn, sz, tm=tm),
+        one_chip, ((M, d), jnp.bfloat16), ((held, d, f), jnp.bfloat16),
+        ((held, d, f), jnp.bfloat16), ((held, f, d), jnp.bfloat16),
+        ((held + 1,), jnp.int32))
+    _assert_kernel(text, "moe_gmm")
 
 
 @pytest.mark.parametrize("S", [64, 512])
